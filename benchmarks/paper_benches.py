@@ -32,17 +32,18 @@ def _t(fn, *args, reps=5, warmup=2):
     return (time.perf_counter() - t0) / reps * 1e6, out
 
 
-def _subprocess_bench_json(script: str, error_name: str):
-    """Run a multi-device bench snippet in a subprocess (forced host
-    devices need their own process) and parse its last stdout line as
-    JSON. -> (data, None) on success, (None, error_row) on failure."""
+def _subprocess_bench_json(script: str, name: str):
+    """Run a multi-device bench snippet in a subprocess on forced host CPU
+    devices (they need their own process, and the parent may hold the
+    chip) and parse its last stdout line as JSON. A failed child raises."""
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=900, env=env)
     if r.returncode != 0:
-        return None, (error_name, 0.0, r.stderr.strip()[-120:])
-    return json.loads(r.stdout.strip().splitlines()[-1]), None
+        raise RuntimeError(f"{name} child failed (rc={r.returncode}): "
+                           f"{r.stderr.strip()[-400:]}")
+    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
 def fig1_direct_io():
@@ -97,9 +98,7 @@ for name, body in {
     out[name] = {"intra": a.coll_wire_intra, "cross": a.coll_wire_cross}
 print(json.dumps(out))
 """ % (os.path.join(ROOT, "src"),)
-    data, err = _subprocess_bench_json(script, "table2_error")
-    if err:
-        return [err]
+    data = _subprocess_bench_json(script, "table2")
     return [(f"table2_sync_{name}", 0.0,
              f"wire_intra={d['intra']:.3g}_cross={d['cross']:.3g}")
             for name, d in data.items()]
@@ -212,9 +211,7 @@ for engine in ("device", "host"):
                    "maxshardpad": max(st.shard_padded_ratio)}
 print(json.dumps(out))
 """ % (os.path.join(ROOT, "src"),)
-    data, err = _subprocess_bench_json(script, "fig3_sharded_error")
-    if err:
-        return [err]
+    data = _subprocess_bench_json(script, "fig3_sharded")
     return [(f"fig3_sharded_{engine}_8shard", d["us"],
              f"pairs={d['pairs']}_nshards={d['n_shards']}"
              f"_maxshardpad={d['maxshardpad']:.2f}")
@@ -537,9 +534,7 @@ print(json.dumps({"us": rs[0].stats.wall_s * 1e6,
                   "pairs": int(rs[0].output),
                   "n_shards": rs[0].stats.n_shards}))
 """ % (os.path.join(ROOT, "src"),)
-    d, err = _subprocess_bench_json(script, "table3_sharded_error")
-    if err:
-        return [err]
+    d = _subprocess_bench_json(script, "table3_sharded")
     return [("table3_search+stats_sharded_8shard", d["us"],
              f"pairs={d['pairs']}_nshards={d['n_shards']}_engine=device")]
 

@@ -9,6 +9,8 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):   # `python benchmarks/run.py`
 
 
 def main() -> None:
+    from repro.core.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks.paper_benches import ALL
     print("name,us_per_call,derived")
     failures = 0

@@ -32,6 +32,7 @@ import tempfile
 
 import numpy as np
 
+from repro.core.compile_cache import use_compile_cache
 from repro.data import MemmapCatalogSplits, sky
 from repro.mapreduce import (ZonePartitioner, available_codecs,
                              neighbor_search_job, neighbor_statistics_job,
@@ -53,6 +54,7 @@ def main():
     ap.add_argument("--n", type=int, default=50000)
     ap.add_argument("--radius", type=float, default=0.02)
     args = ap.parse_args()
+    use_compile_cache()
 
     print(f"== synthetic catalog: {args.n} objects ==")
     xyz = sky.make_catalog(args.n, seed=0)

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import RunConfig, get_arch
+from repro.core.compile_cache import use_compile_cache
 from repro.launch.mesh import make_cpu_mesh
 from repro.launch.train import train
 from repro.models import model as mdl
@@ -14,6 +15,7 @@ from repro.serving.engine import Request, ServeEngine
 
 
 def main():
+    use_compile_cache()
     cfg = get_arch("tinyllama-1.1b").reduced()
     rc = RunConfig(remat="none", steps=30, warmup_steps=3, learning_rate=1e-3)
     mesh = make_cpu_mesh()

@@ -9,6 +9,7 @@ import jax
 import numpy as np
 
 from repro.configs import RunConfig, get_arch
+from repro.core.compile_cache import use_compile_cache
 from repro.launch.mesh import make_cpu_mesh
 from repro.models import model as mdl
 from repro.parallel.sharding import make_rules, use_mesh
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=24)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch).reduced()
     rc = RunConfig(remat="none")

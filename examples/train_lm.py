@@ -11,6 +11,7 @@ import argparse
 import dataclasses
 
 from repro.configs import RunConfig, get_arch
+from repro.core.compile_cache import use_compile_cache
 from repro.launch.mesh import make_cpu_mesh
 from repro.launch.train import train
 
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--ckpt", default="/tmp/repro_lm100m_ckpt")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = lm_100m()
     from repro.models.model import count_params_total

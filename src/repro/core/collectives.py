@@ -23,7 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.compression import axis_size, compressed_psum_1d
+from repro.core.compression import compressed_psum_1d
 
 
 def flat_psum(x, axes: tuple[str, ...]):
@@ -43,7 +43,7 @@ def hierarchical_psum_1d(x, inner_axis: str | None, outer_axis: str | None,
             return x
         return (compressed_psum_1d(x, outer_axis) if codec == "int8"
                 else jax.lax.psum(x, outer_axis))
-    R = axis_size(inner_axis)
+    R = jax.lax.axis_size(inner_axis)
     pad = (-n) % R
     xp = jnp.pad(x, (0, pad))
     shard = jax.lax.psum_scatter(xp.reshape(R, -1), inner_axis,

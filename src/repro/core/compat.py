@@ -1,47 +1,23 @@
-"""jax version compatibility shims (single home for try/except-API code).
-
-The repo targets current jax but must degrade gracefully on older releases
-(no ``jax.shard_map``, no ``jax.sharding.AxisType``, no ``jax.lax.axis_size``).
-Only fully-manual shard_map regions can be expressed on old jax; callers that
-need partial-manual axes (``axis_names`` a strict subset of the mesh) should
-keep using ``jax.shard_map`` directly and document the version floor.
-"""
+"""Mesh and shard_map helpers shared by every sharded code path."""
 from __future__ import annotations
 
 import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis_types when supported."""
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) *
-                             len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names):
-    """jax.shard_map, falling back to jax.experimental.shard_map (which is
-    fully manual: the fallback treats every mesh axis as manual, so only use
-    this for regions where ``axis_names`` covers all axes the body touches
-    collectively and the specs fully describe the partitioning).
+    """``jax.shard_map`` returned jit-wrapped.
 
-    Closed-over arrays: bodies may close over jax Arrays (decoded tier
-    payloads, codec constants). On jax 0.4.x the *eager* experimental
-    shard_map refuses operands/closures committed to a single device
-    ("incompatible devices for jitted computation") while ``jit(shard_map)``
-    happily reshards them onto the mesh — so the fallback is returned
-    jit-wrapped. Nested jit is a no-op for callers that already jit.
-    """
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    except (AttributeError, TypeError):
-        # Fully-manual fallback: axes outside the specs are replicated. Old
-        # shard_map's `auto=` (partial-manual) hits XLA partitioner RET_CHECK
-        # failures on gathers, so it is deliberately NOT used here.
-        from jax.experimental.shard_map import shard_map as _sm
-        return jax.jit(_sm(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False))
+    Eager partial-manual ``shard_map`` (``axis_names`` a strict subset of
+    the mesh, e.g. ``{"data"}`` on a ``("data", "model")`` mesh) refuses an
+    ``out_specs=P()`` that the jitted form accepts, and jit also reshards
+    operands committed to a single device onto the mesh. Nested jit is a
+    no-op for callers that already jit."""
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, axis_names=axis_names,
+                                 check_vma=False))

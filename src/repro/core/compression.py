@@ -24,22 +24,6 @@ import jax.numpy as jnp
 BLOCK = 256
 
 
-def axis_size(axis_name) -> int:
-    """Static mesh-axis size inside a shard_map body, across jax versions
-    (older jax has no ``jax.lax.axis_size``; tuple names multiply)."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        from jax import core
-        names = (axis_name if isinstance(axis_name, (tuple, list))
-                 else (axis_name,))
-        size = 1
-        for nm in names:
-            frame = core.axis_frame(nm)
-            size *= getattr(frame, "size", frame)
-        return int(size)
-
-
 def int8_wire_bytes(n: int, block: int = BLOCK) -> int:
     """Wire bytes for a block-quantized payload of ``n`` scalars: one int8
     code per element plus one fp32 scale per block (zero-padded to a full
@@ -102,7 +86,7 @@ def compressed_psum_1d(x, axis_name, block: int = BLOCK):
     quantized all-gather. Wire bytes ~= n int8 both phases vs 2n bf16 for a ring
     all-reduce (4x reduction + scales overhead).
     """
-    R = axis_size(axis_name)
+    R = jax.lax.axis_size(axis_name)
     if R == 1:
         return x
     n = x.shape[0]

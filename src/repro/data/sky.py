@@ -35,10 +35,28 @@ def n_zones(zone_height: float) -> int:
 
 
 def brute_force_pairs(xyz: np.ndarray, radius_rad: float) -> int:
-    """O(n^2) oracle: number of unordered pairs within radius."""
-    dots = xyz @ xyz.T
-    np.fill_diagonal(dots, -2.0)
-    return int(np.sum(dots >= np.cos(radius_rad)) // 2)
+    """O(n^2) oracle: number of unordered pairs within radius.
+
+    A pair is within radius when its float32 dot ``(x0*y0 + x1*y1) + x2*y2``
+    (each product and sum rounded, no fused multiply-add) is >= the float32
+    ``cos(radius)``: the scores of the Pallas kernel. A BLAS matmul fuses
+    and reorders the sum, and XLA:CPU contracts products and sums into
+    fused multiply-adds; either flips pairs sitting within an ulp of the
+    threshold once catalogs reach ~1e4 rows. Blocks of 2048 rows keep
+    memory at O(2048 * n)."""
+    block = 2048
+    x = np.asarray(xyz, np.float32)
+    cmin = np.float32(np.cos(radius_rad))
+    hits = 0
+    for lo in range(0, len(x), block):
+        a = x[lo:lo + block]
+        dots = a[:, None, 0] * x[None, :, 0]
+        for k in range(1, x.shape[1]):
+            dots += a[:, None, k] * x[None, :, k]
+        ok = dots >= cmin
+        ok[np.arange(len(a)), lo + np.arange(len(a))] = False
+        hits += int(np.count_nonzero(ok))
+    return hits // 2
 
 
 def brute_force_hist(xyz: np.ndarray, edges_rad: np.ndarray) -> np.ndarray:

@@ -1,118 +1,40 @@
-"""Pallas TPU kernel: blockwise pair search for the Zones algorithm.
+"""Pallas TPU kernel: masked-batched pair search for the Zones algorithm.
 
-The compute hot spot of the paper's Neighbor Searching / Neighbor Statistics apps:
-for two tiles of unit vectors, form the [TM, TN] dot-product tile on the MXU and
-reduce (count >= cos_min, or cumulative per-edge counts for the histogram app).
-The [TM, TN] score tile lives only in VMEM — the analogue of the paper's insight that
-the reducer should never write O(n^2) intermediates.
+The compute hot spot of the paper's Neighbor Searching / Neighbor Statistics
+apps. Grid ``(P, M/TM, N/TN)``: a leading *partition* axis over a whole
+capacity tier, then (owned-tile, bucket-tile) pairs. Per-partition real
+counts ``n_a``/``n_b`` mask rows/cols beyond the real count in-kernel, so
+capacity padding contributes zero regardless of the pad fill, and tiles that
+are all padding are skipped. The [TM, TN] score tile lives only in VMEM —
+the analogue of the paper's insight that the reducer should never write
+O(n^2) intermediates.
 
-Grid is (M/TM, N/TN); per-tile partial results land in an [gm, gn] (or [gm, gn, NB])
-output that the caller sums — keeping the kernel free of cross-tile accumulation.
+Scores are the rounded product sum ``(a0*b0 + a1*b1) + a2*b2`` on the VPU,
+the ``ref._dots2d`` formulation every engine shares, so counts are
+bit-identical to the references. An MXU ``dot_general`` would not be: its
+default f32 precision is one bf16 pass (about 4e-3 error on a unit-vector
+dot against a ``1 - cos r`` margin of 2e-4 at r = 0.02 rad), and K=3 gains
+nothing on the MXU anyway.
 
-The ``*_masked_pallas`` variants add a leading *partition* grid axis
-(grid ``(P, M/TM, N/TN)``) with per-partition real counts ``n_a``/``n_b``:
-rows/cols beyond the real count are masked out in-kernel, so capacity padding
-contributes zero regardless of the pad fill — the engine="device" batched
-reduce runs every partition of a size tier in ONE kernel launch instead of a
-sequential ``lax.map``.
+Layout: owned rows stay ``[P, M, d]``; bucket rows are passed transposed,
+``[P, d, N]``, so a bucket coordinate is a lane-dense row that broadcasts
+against an owned column. Scalars (the per-partition counts and the cos
+edges) live in SMEM. Each partition accumulates its cumulative per-edge
+counts in ONE resident lane-dense ``(R, 128)`` int32 block across its
+``(i, j)`` steps (edge ``k`` at row ``k // 128``, lane ``k % 128``), so the
+output is ``P`` small tiles, not one per grid step.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TM = 256
 TN = 256
+_LANES = 128
 
-
-def _count_kernel(a_ref, b_ref, cmin_ref, o_ref, *, exclude_self: bool):
-    a = a_ref[...].astype(jnp.float32)              # [TM, 3->pad]
-    b = b_ref[...].astype(jnp.float32)              # [TN, 3->pad]
-    dots = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    ok = dots >= cmin_ref[0]
-    if exclude_self:
-        i = pl.program_id(0)
-        j = pl.program_id(1)
-        tm, tn = dots.shape
-        ri = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0) + i * tm
-        rj = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 1) + j * tn
-        ok = ok & (ri != rj)
-    o_ref[0, 0] = jnp.sum(ok.astype(jnp.int32))
-
-
-def _hist_kernel(a_ref, b_ref, edges_ref, o_ref, *, exclude_self: bool):
-    a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    dots = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    if exclude_self:
-        i = pl.program_id(0)
-        j = pl.program_id(1)
-        tm, tn = dots.shape
-        ri = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0) + i * tm
-        rj = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 1) + j * tn
-        dots = jnp.where(ri == rj, -2.0, dots)
-    edges = edges_ref[...]                           # [NB]
-    nb = edges.shape[0]
-
-    def bin_body(k, _):
-        o_ref[0, 0, k] = jnp.sum((dots >= edges[k]).astype(jnp.int32))
-        return 0
-
-    jax.lax.fori_loop(0, nb, bin_body, 0)
-
-
-def _pad3(x):
-    """Pad the coordinate dim 3 -> 128 (lane alignment); zeros don't affect dots."""
-    pad = [(0, 0)] * (x.ndim - 1) + [(0, 128 - x.shape[-1])]
-    return jnp.pad(x, pad)
-
-
-def pair_count_pallas(a, b, cos_min, *, exclude_self: bool = False,
-                      tm: int = TM, tn: int = TN, interpret: bool = False):
-    M, N = a.shape[0], b.shape[0]
-    assert M % tm == 0 and N % tn == 0, (M, N, tm, tn)
-    gm, gn = M // tm, N // tn
-    cmin = jnp.full((1,), cos_min, jnp.float32)
-    out = pl.pallas_call(
-        functools.partial(_count_kernel, exclude_self=exclude_self),
-        grid=(gm, gn),
-        in_specs=[pl.BlockSpec((tm, 128), lambda i, j: (i, 0)),
-                  pl.BlockSpec((tn, 128), lambda i, j: (j, 0)),
-                  pl.BlockSpec((1,), lambda i, j: (0,))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((gm, gn), jnp.int32),
-        interpret=interpret,
-    )(_pad3(a), _pad3(b), cmin)
-    return jnp.sum(out, dtype=jnp.int32)
-
-
-def pair_hist_pallas(a, b, cos_edges, *, exclude_self: bool = False,
-                     tm: int = TM, tn: int = TN, interpret: bool = False):
-    M, N = a.shape[0], b.shape[0]
-    assert M % tm == 0 and N % tn == 0, (M, N, tm, tn)
-    gm, gn = M // tm, N // tn
-    nbins = cos_edges.shape[0]
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel, exclude_self=exclude_self),
-        grid=(gm, gn),
-        in_specs=[pl.BlockSpec((tm, 128), lambda i, j: (i, 0)),
-                  pl.BlockSpec((tn, 128), lambda i, j: (j, 0)),
-                  pl.BlockSpec((nbins,), lambda i, j: (0,))],
-        out_specs=pl.BlockSpec((1, 1, nbins), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((gm, gn, nbins), jnp.int32),
-        interpret=interpret,
-    )(_pad3(a), _pad3(b), jnp.asarray(cos_edges, jnp.float32))
-    return jnp.sum(out, axis=(0, 1), dtype=jnp.int32)
-
-
-# ---------------------------------------------------------------------------
-# Masked-batched variants: leading partition grid axis + n_a/n_b masking
-# ---------------------------------------------------------------------------
 
 def _fit_tile(C: int, t: int) -> int:
     """Largest divisor of C that is <= t — keeps VMEM blocks bounded even
@@ -124,90 +46,66 @@ def _fit_tile(C: int, t: int) -> int:
     return t
 
 
-def _tile_validity(na, nb, i, j, tm, tn):
-    """[tm, tn] bool: (row, col) is a real (non-padded) pair of this tile."""
-    ri = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0) + i * tm
-    rj = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 1) + j * tn
-    return (ri < na) & (rj < nb)
+def _hist_masked_kernel(na_ref, nb_ref, edges_ref, a_ref, bt_ref, o_ref):
+    p, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    tm, tn = a_ref.shape[1], bt_ref.shape[2]
+    na, nb = na_ref[p], nb_ref[p]
 
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.int32)
 
-def _count_masked_kernel(a_ref, b_ref, cmin_ref, na_ref, nb_ref, o_ref):
-    i, j = pl.program_id(1), pl.program_id(2)
-    a = a_ref[0].astype(jnp.float32)                # [tm, 128]
-    b = b_ref[0].astype(jnp.float32)                # [tn, 128]
-    o_ref[0, 0, 0] = 0
-
-    @pl.when((pl.program_id(1) * a.shape[0] < na_ref[0])
-             & (pl.program_id(2) * b.shape[0] < nb_ref[0]))
-    def _():                                        # skip all-padding tiles
-        dots = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        ok = (dots >= cmin_ref[0]) & _tile_validity(
-            na_ref[0], nb_ref[0], i, j, *dots.shape)
-        o_ref[0, 0, 0] = jnp.sum(ok.astype(jnp.int32))
-
-
-def _hist_masked_kernel(a_ref, b_ref, edges_ref, na_ref, nb_ref, o_ref):
-    i, j = pl.program_id(1), pl.program_id(2)
-    a = a_ref[0].astype(jnp.float32)
-    b = b_ref[0].astype(jnp.float32)
-    dots = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    dots = jnp.where(_tile_validity(na_ref[0], nb_ref[0], i, j, *dots.shape),
-                     dots, -2.0)
-    edges = edges_ref[...]                           # [NB]
-
-    def bin_body(k, _):
-        o_ref[0, 0, 0, k] = jnp.sum((dots >= edges[k]).astype(jnp.int32))
-        return 0
-
-    jax.lax.fori_loop(0, edges.shape[0], bin_body, 0)
-
-
-def pair_count_masked_pallas(a, b, n_a, n_b, cos_min, *, tm: int = TM,
-                             tn: int = TN, interpret: bool = False):
-    """a: [P,M,3], b: [P,N,3] (any float dtype), n_a/n_b: [P] int32 real
-    counts. -> total masked pair count (scalar int32)."""
-    P, M, _ = a.shape
-    N = b.shape[1]
-    tm, tn = _fit_tile(M, tm), _fit_tile(N, tn)
-    gm, gn = M // tm, N // tn
-    cmin = jnp.full((1,), cos_min, jnp.float32)
-    out = pl.pallas_call(
-        _count_masked_kernel,
-        grid=(P, gm, gn),
-        in_specs=[pl.BlockSpec((1, tm, 128), lambda p, i, j: (p, i, 0)),
-                  pl.BlockSpec((1, tn, 128), lambda p, i, j: (p, j, 0)),
-                  pl.BlockSpec((1,), lambda p, i, j: (0,)),
-                  pl.BlockSpec((1,), lambda p, i, j: (p,)),
-                  pl.BlockSpec((1,), lambda p, i, j: (p,))],
-        out_specs=pl.BlockSpec((1, 1, 1), lambda p, i, j: (p, i, j)),
-        out_shape=jax.ShapeDtypeStruct((P, gm, gn), jnp.int32),
-        interpret=interpret,
-    )(_pad3(a), _pad3(b), cmin, jnp.asarray(n_a, jnp.int32),
-      jnp.asarray(n_b, jnp.int32))
-    return jnp.sum(out, dtype=jnp.int32)
+    @pl.when((i * tm < na) & (j * tn < nb))          # skip all-padding tiles
+    def _():
+        a = a_ref[0].astype(jnp.float32)             # [tm, d]
+        bt = bt_ref[0].astype(jnp.float32)           # [d, tn]
+        dots = a[:, 0:1] * bt[0:1, :]
+        for k in range(1, a.shape[1]):               # _dots2d's sum order
+            dots = dots + a[:, k:k + 1] * bt[k:k + 1, :]
+        ri = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0) + i * tm
+        rj = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 1) + j * tn
+        dots = jnp.where((ri < na) & (rj < nb), dots, -2.0)
+        rows = o_ref.shape[1]
+        cell = (jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 0)
+                * _LANES
+                + jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1))
+        acc = o_ref[0]
+        for k in range(edges_ref.shape[0]):          # static: few edges
+            hits = jnp.sum((dots >= edges_ref[k]).astype(jnp.int32))
+            acc = acc + jnp.where(cell == k, hits, 0)
+        o_ref[0] = acc
 
 
 def pair_hist_masked_pallas(a, b, n_a, n_b, cos_edges, *, tm: int = TM,
                             tn: int = TN, interpret: bool = False):
-    """Masked-batched cumulative per-edge counts, summed over partitions."""
-    P, M, _ = a.shape
+    """a: [P,M,d], b: [P,N,d] (any float dtype), n_a/n_b: [P] int32 real
+    counts, cos_edges: [NB]. -> [NB] int32 cumulative counts
+    ``#{valid (p,i,j): a[p,i] . b[p,j] >= cos_edges[k]}``."""
+    P, M, d = a.shape
     N = b.shape[1]
     tm, tn = _fit_tile(M, tm), _fit_tile(N, tn)
-    gm, gn = M // tm, N // tn
     nbins = cos_edges.shape[0]
+    rows = 8 * max(1, -(-nbins // (8 * _LANES)))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         _hist_masked_kernel,
-        grid=(P, gm, gn),
-        in_specs=[pl.BlockSpec((1, tm, 128), lambda p, i, j: (p, i, 0)),
-                  pl.BlockSpec((1, tn, 128), lambda p, i, j: (p, j, 0)),
-                  pl.BlockSpec((nbins,), lambda p, i, j: (0,)),
-                  pl.BlockSpec((1,), lambda p, i, j: (p,)),
-                  pl.BlockSpec((1,), lambda p, i, j: (p,))],
-        out_specs=pl.BlockSpec((1, 1, 1, nbins), lambda p, i, j: (p, i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((P, gm, gn, nbins), jnp.int32),
+        grid=(P, M // tm, N // tn),
+        in_specs=[smem, smem, smem,
+                  pl.BlockSpec((1, tm, d), lambda p, i, j: (p, i, 0)),
+                  pl.BlockSpec((1, d, tn), lambda p, i, j: (p, 0, j))],
+        out_specs=pl.BlockSpec((1, rows, _LANES), lambda p, i, j: (p, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((P, rows, _LANES), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(_pad3(a), _pad3(b), jnp.asarray(cos_edges, jnp.float32),
-      jnp.asarray(n_a, jnp.int32), jnp.asarray(n_b, jnp.int32))
-    return jnp.sum(out, axis=(0, 1, 2), dtype=jnp.int32)
+    )(jnp.asarray(n_a, jnp.int32), jnp.asarray(n_b, jnp.int32),
+      jnp.asarray(cos_edges, jnp.float32), a, jnp.swapaxes(b, 1, 2))
+    return jnp.sum(out, axis=0, dtype=jnp.int32).reshape(-1)[:nbins]
+
+
+def pair_count_masked_pallas(a, b, n_a, n_b, cos_min, *, tm: int = TM,
+                             tn: int = TN, interpret: bool = False):
+    """Masked pair count: the one-edge histogram. -> scalar int32."""
+    edges = jnp.full((1,), cos_min, jnp.float32)
+    return pair_hist_masked_pallas(a, b, n_a, n_b, edges, tm=tm, tn=tn,
+                                   interpret=interpret)[0]

@@ -15,6 +15,7 @@ import time
 
 import numpy as np
 
+from repro.core.compile_cache import use_compile_cache
 from repro.data import sky
 from repro.mapreduce import (ZonePartitioner, neighbor_search_job,
                              neighbor_statistics_job)
@@ -50,6 +51,7 @@ def main():
     ap.add_argument("--max-batch", type=int, default=16)
     ap.add_argument("--max-wait-ms", type=float, default=2.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     xyz = sky.make_catalog(args.n, 0)
     part = ZonePartitioner(args.radius)

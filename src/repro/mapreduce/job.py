@@ -560,26 +560,26 @@ def plan_tiers(n_owned, n_bucket, tile: int, max_tiers: int = 3,
     return build(best_cuts)
 
 
-@functools.partial(jax.jit, static_argnames=("specs", "has_skey"))
-def _scatter_tiers_jit(payloads, keys, dest_eff, src, skey, owned_starts,
-                       bucket_starts, part_tier, part_local, *, specs,
-                       has_skey):
-    """Argsort-based bucketing: sort bucket entries by (destination, sort
-    key), compute each entry's rank within its partition from the
-    exclusive-cumsum starts, and scatter the *wire-dtype* payload rows into
-    every tier's padded [Pt, C, ...] layout (entries outside the tier drop
-    out of range).
+@functools.partial(jax.jit, static_argnames=("specs",))
+def _scatter_tiers_jit(payloads, keys, dest_eff, src, owned_starts,
+                       bucket_starts, part_tier, part_local, *, specs):
+    """Argsort-based bucketing: sort bucket entries by destination, compute
+    each entry's rank within its partition from the exclusive-cumsum
+    starts, and scatter the *wire-dtype* payload rows into every tier's
+    padded [Pt, C, ...] layout (entries outside the tier drop out of
+    range).
 
     ``dest_eff`` is [m] with invalid entries set to P (they sort last and
     hit ``part_tier[P] == -1``, so no tier claims them).
+
+    Rows keep arrival order within a partition: the partitioner's secondary
+    sort key only tightens the blocked reduce's z pruning, which the Pallas
+    reduce of this (accelerator) path does not do, and a two-key sort costs
+    the TPU compiler ~100 s more than a one-key argsort.
     """
     n, m = keys.shape[0], dest_eff.shape[0]
-    if has_skey:
-        ko = jnp.lexsort((skey, keys))
-        bo = jnp.lexsort((skey[src], dest_eff))
-    else:
-        ko = jnp.argsort(keys)
-        bo = jnp.argsort(dest_eff)
+    ko = jnp.argsort(keys)
+    bo = jnp.argsort(dest_eff)
     sk = keys[ko]
     orank = jnp.arange(n, dtype=jnp.int32) - owned_starts[sk]
     sd = dest_eff[bo]
@@ -612,7 +612,9 @@ def _scatter_tiers_jit(payloads, keys, dest_eff, src, skey, owned_starts,
 # The RESOLVED choice is recorded in ``StageStats.shuffle_index_impl``
 # ("jnp" | "host") so an "auto" run under a mesh is never ambiguous about
 # which path produced its shuffle metadata; both paths must produce
-# identical tier layouts and results (asserted in tests and md_check).
+# identical tier shapes and results (asserted in tests and md_check). Row
+# order within a partition differs: only the host path sorts by the
+# partitioner's secondary key (see ``_scatter_tiers_jit``).
 SHUFFLE_INDEX_IMPL = "auto"            # "auto" | "jnp" | "host"
 
 
@@ -979,10 +981,9 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
     if _use_jnp_indices():
         scattered = _scatter_tiers_jit(
             m.payloads, m.keys, m.dest_eff, m.src,
-            jnp.zeros(0) if m.skey is None else m.skey,
             jnp.asarray(o_starts), jnp.asarray(b_starts),
             jnp.asarray(part_tier), jnp.asarray(part_local),
-            specs=tuple(specs), has_skey=m.skey is not None)
+            specs=tuple(specs))
     else:
         src_h = np.asarray(m.src)
         live = dest_h < P           # drop non-replicated border slots before

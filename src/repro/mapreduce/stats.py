@@ -36,8 +36,7 @@ class PairHistReducer(Reducer):
         return jnp.asarray(np.cos(np.asarray(self.edges_rad)), jnp.float32)
 
     def per_partition(self, owned_p, bucket_p):
-        return pair_hist(owned_p, bucket_p, self._cos_edges(),
-                         use_pallas=self.use_pallas)
+        return pair_hist(owned_p, bucket_p, self._cos_edges())
 
     def reduce_partitions(self, owned, bucket, n_owned, n_bucket):
         return pair_hist_masked(owned, bucket, n_owned, n_bucket,

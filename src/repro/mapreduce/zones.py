@@ -114,8 +114,7 @@ class PairCountReducer(Reducer):
     use_pallas: bool | None = None
 
     def per_partition(self, owned_p, bucket_p):
-        return pair_count(owned_p, bucket_p, float(np.cos(self.radius)),
-                          use_pallas=self.use_pallas)
+        return pair_count(owned_p, bucket_p, float(np.cos(self.radius)))
 
     def reduce_partitions(self, owned, bucket, n_owned, n_bucket):
         return pair_count_masked(owned, bucket, n_owned, n_bucket,

@@ -58,7 +58,7 @@ print("cache specs OK")
 @pytest.mark.slow
 def test_cache_specs_on_production_mesh():
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run([sys.executable, "-c", SCRIPT % os.path.abspath(src)],
                        capture_output=True, text=True, timeout=600, env=env)

@@ -11,9 +11,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.quantize.kernel import dequantize_pallas, quantize_pallas
 from repro.kernels.quantize.ref import dequantize_ref, quantize_ref
 from repro.kernels.zones_pairs.kernel import (pair_count_masked_pallas,
-                                              pair_count_pallas,
-                                              pair_hist_masked_pallas,
-                                              pair_hist_pallas)
+                                              pair_hist_masked_pallas)
 from repro.kernels.zones_pairs.ref import pair_count_ref, pair_hist_ref
 
 
@@ -48,35 +46,45 @@ def test_quantize_sweep(rng, rows, cols, dtype):
 # zones_pairs
 # ---------------------------------------------------------------------------
 
+# The masked kernels at P=1 with full counts: one dense (a, b) block pair.
+
+def _full(x):
+    return x[None], jnp.asarray([x.shape[0]], jnp.int32)
+
+
 @pytest.mark.parametrize("m,n,tm,tn", [(256, 256, 256, 256),
                                        (512, 256, 256, 256),
                                        (512, 512, 128, 256)])
 @pytest.mark.parametrize("radius", [0.02, 0.1])
 def test_pair_count_sweep(m, n, tm, tn, radius):
-    a = jnp.asarray(sky.make_catalog(m, 1))
-    b = jnp.asarray(sky.make_catalog(n, 2))
+    a, na = _full(jnp.asarray(sky.make_catalog(m, 1)))
+    b, nb = _full(jnp.asarray(sky.make_catalog(n, 2)))
     cm = float(np.cos(radius))
-    got = pair_count_pallas(a, b, cm, tm=tm, tn=tn, interpret=True)
-    want = pair_count_ref(a, b, cm)
+    got = pair_count_masked_pallas(a, b, na, nb, cm, tm=tm, tn=tn,
+                                   interpret=True)
+    want = pair_count_ref(a[0], b[0], cm)
     assert int(got) == int(want)
 
 
 def test_pair_count_exclude_self():
-    a = jnp.asarray(sky.make_catalog(256, 3))
+    """Self pairs: every unit vector scores ~1 against itself, so the masked
+    count of (a, a) minus the diagonal is the exclude-self reference."""
+    a, na = _full(jnp.asarray(sky.make_catalog(256, 3)))
     cm = float(np.cos(0.05))
-    got = pair_count_pallas(a, a, cm, exclude_self=True, tm=128, tn=128,
-                            interpret=True)
-    want = pair_count_ref(a, a, cm, exclude_self=True)
-    assert int(got) == int(want)
+    got = pair_count_masked_pallas(a, a, na, na, cm, tm=128, tn=128,
+                                   interpret=True)
+    want = pair_count_ref(a[0], a[0], cm, exclude_self=True)
+    assert int(got) - 256 == int(want)
 
 
 @pytest.mark.parametrize("nbins", [4, 16, 60])
 def test_pair_hist_sweep(nbins):
-    a = jnp.asarray(sky.make_catalog(256, 4))
-    b = jnp.asarray(sky.make_catalog(512, 5))
+    a, na = _full(jnp.asarray(sky.make_catalog(256, 4)))
+    b, nb = _full(jnp.asarray(sky.make_catalog(512, 5)))
     edges = jnp.asarray(np.cos(np.linspace(0.01, 0.2, nbins)), jnp.float32)
-    got = pair_hist_pallas(a, b, edges, tm=256, tn=256, interpret=True)
-    want = pair_hist_ref(a, b, edges)
+    got = pair_hist_masked_pallas(a, b, na, nb, edges, tm=256, tn=256,
+                                  interpret=True)
+    want = pair_hist_ref(a[0], b[0], edges)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

@@ -290,7 +290,7 @@ def test_ragged_shards_match_host_mesh_oracle():
     match the host mesh oracle exactly (8 host devices, subprocess)."""
     script = os.path.join(os.path.dirname(__file__), "md_check.py")
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, script, "mapreduce-ragged"],
                        capture_output=True, text=True, timeout=900, env=env)
     assert r.returncode == 0, (
@@ -455,7 +455,7 @@ def test_streaming_matches_monolithic_on_mesh():
     mesh, incl. wordcount with the combiner on/off (subprocess)."""
     script = os.path.join(os.path.dirname(__file__), "md_check.py")
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, script, "mapreduce-streaming"],
                        capture_output=True, text=True, timeout=900, env=env)
     assert r.returncode == 0, (
@@ -546,7 +546,7 @@ def test_wrappers_warn_deprecation():
 def test_sharded_mesh_matches_single_device():
     script = os.path.join(os.path.dirname(__file__), "md_check.py")
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, script, "mapreduce"],
                        capture_output=True, text=True, timeout=900, env=env)
     assert r.returncode == 0, f"mapreduce check failed:\n{r.stdout}\n{r.stderr}"

@@ -241,7 +241,7 @@ def test_service_sharded_multidevice():
     catalog == per-query mesh run == host oracle, with coalescing)."""
     script = os.path.join(os.path.dirname(__file__), "md_check.py")
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, script, "mapreduce-service"],
                        capture_output=True, text=True, timeout=900, env=env)
     assert r.returncode == 0, (

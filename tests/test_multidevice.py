@@ -3,19 +3,13 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 SCRIPT = os.path.join(os.path.dirname(__file__), "md_check.py")
 
-# partial-manual shard_map (manual DP axes, auto model axis) needs current
-# jax; the 0.4.x fallback is fully manual and trips XLA on the model axis
-_OLD_JAX = not hasattr(jax, "shard_map")
-
-
 def _run(check: str, timeout: int = 900):
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, SCRIPT, check], capture_output=True,
                        text=True, timeout=timeout, env=env)
     assert r.returncode == 0, f"{check} failed:\n{r.stdout}\n{r.stderr}"
@@ -38,8 +32,6 @@ def test_moe_expert_parallel_multidevice():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(_OLD_JAX, reason="explicit train path needs partial-"
-                    "manual shard_map (current jax)")
 def test_train_modes_multidevice():
     assert "OK" in _run("train")
 

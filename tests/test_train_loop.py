@@ -9,11 +9,6 @@ from repro.configs import RunConfig, get_arch
 from repro.launch.train import train
 
 
-# Resuming a donated train step from a restored checkpoint segfaults jaxlib
-# 0.4.x on CPU; the resume tests need current jax (they run in CI).
-_OLD_JAX = not hasattr(jax, "shard_map")
-
-
 def _rc(steps):
     return RunConfig(remat="none", steps=steps, warmup_steps=2,
                      learning_rate=1e-3)
@@ -43,7 +38,6 @@ def test_loss_decreases_on_learnable_data(cpu_mesh, tmp_path):
     assert losses[-1] < losses[0] - 0.5, losses[:3] + losses[-3:]
 
 
-@pytest.mark.skipif(_OLD_JAX, reason="ckpt-resume segfaults jaxlib 0.4.x CPU")
 def test_checkpoint_resume_matches_uninterrupted(cpu_mesh, tmp_path):
     cfg = get_arch("tinyllama-1.1b").reduced()
     d1 = str(tmp_path / "a")
@@ -61,7 +55,6 @@ def test_checkpoint_resume_matches_uninterrupted(cpu_mesh, tmp_path):
     np.testing.assert_allclose(losses_full[4:], losses_resumed, rtol=1e-4)
 
 
-@pytest.mark.skipif(_OLD_JAX, reason="ckpt-resume segfaults jaxlib 0.4.x CPU")
 def test_failure_injection_and_restart(cpu_mesh, tmp_path):
     cfg = get_arch("tinyllama-1.1b").reduced()
     d = str(tmp_path / "ckpt")
