@@ -874,21 +874,35 @@ def run_jobs_streaming(jobs, source: SplitSource, *, mesh=None,
     tr = get_tracer()
     meter = get_meter()
     mtok = meter.begin()
-    if (n_lanes > 1 or policy is not None or chaos is not None
-            or max_retries > 0 or deadline_s is not None):
-        t_job0 = time.perf_counter()
-        out = _run_jobs_lanes(
-            jobs, source, mesh=mesh, device=device, codec=codec, part=part,
-            comb=comb, K=K, stats=stats, straggler_monitor=straggler_monitor,
-            n_lanes=max(1, int(n_lanes)), policy=policy, chaos=chaos,
-            max_retries=max_retries, retry_backoff_s=retry_backoff_s,
-            deadline_s=deadline_s, spill_cfg=spill_cfg)
-        if tr.enabled:
-            tr.record("job", t_job0, time.perf_counter(), cat="job",
-                      job=stats.job, mode="lanes")
-        meter.attribute(mtok, stats)
-        return out
-    t_job0 = time.perf_counter()
+    lanes = (n_lanes > 1 or policy is not None or chaos is not None
+             or max_retries > 0 or deadline_s is not None)
+    with tr.span("job", cat="job", job=stats.job,
+                 mode="lanes" if lanes else "stream"):
+        if lanes:
+            out = _run_jobs_lanes(
+                jobs, source, mesh=mesh, device=device, codec=codec,
+                part=part, comb=comb, K=K, stats=stats,
+                straggler_monitor=straggler_monitor,
+                n_lanes=max(1, int(n_lanes)), policy=policy, chaos=chaos,
+                max_retries=max_retries, retry_backoff_s=retry_backoff_s,
+                deadline_s=deadline_s, spill_cfg=spill_cfg)
+        else:
+            out = _run_jobs_stream(
+                jobs, source, mesh=mesh, device=device, codec=codec,
+                part=part, comb=comb, K=K, stats=stats,
+                straggler_monitor=straggler_monitor, prefetch=prefetch,
+                spill_cfg=spill_cfg)
+    meter.attribute(mtok, stats)
+    return out
+
+
+def _run_jobs_stream(jobs, source, *, mesh, device, codec, part, comb, K,
+                     stats, straggler_monitor, prefetch, spill_cfg):
+    """The one-lane streaming run: splits map in order (overlapped with
+    their fetch by a ``Prefetcher`` when ``prefetch``), then one global
+    shuffle + reduce, or a per-split reduce folded by the combiner.
+    -> one JobResult per job."""
+    tr = get_tracer()
 
     def fetch(k):
         # -> (items, raw_rows, raw_bytes): the RAW split size is carried
@@ -1033,10 +1047,6 @@ def run_jobs_streaming(jobs, source: SplitSource, *, mesh=None,
     stats.n_items = raw_items_total
     stats.map_bytes = raw_bytes_total
     stats.splits = tuple(recs)
-    if tr.enabled:
-        tr.record("job", t_job0, time.perf_counter(), cat="job",
-                  job=stats.job, mode="stream")
-    meter.attribute(mtok, stats)
     return [JobResult(j.reducer.finalize(t, summary), stats)
             for j, t in zip(jobs, totals)]
 

@@ -371,41 +371,42 @@ def shuffle_stage(items, partitioner: Partitioner, codec="identity", *,
     stats = stats if stats is not None else StageStats()
 
     tr = get_tracer()
-    t0 = time.perf_counter()
-    P = int(partitioner.n_partitions(items))
-    keys = np.asarray(partitioner.assign(items))
-    owned_idx = [np.flatnonzero(keys == k) for k in range(P)]
-    bucket_idx = [[idx] for idx in owned_idx]
-    for dest, idx in partitioner.replicas(items, keys, P):
-        bucket_idx[dest].append(np.asarray(idx))
-    t1 = time.perf_counter()
+    with tr.span("map", cat="stage", engine="host"):
+        t0 = time.perf_counter()
+        P = int(partitioner.n_partitions(items))
+        keys = np.asarray(partitioner.assign(items))
+        owned_idx = [np.flatnonzero(keys == k) for k in range(P)]
+        bucket_idx = [[idx] for idx in owned_idx]
+        for dest, idx in partitioner.replicas(items, keys, P):
+            bucket_idx[dest].append(np.asarray(idx))
+        t1 = time.perf_counter()
     stats.map_wall_s = t1 - t0
     stats.map_bytes = items.nbytes
-    if tr.enabled:
-        tr.record("map", t0, t1, cat="stage", engine="host")
 
-    t0 = time.perf_counter()
-    decoded = codec.roundtrip(items).astype(np.float32)
-    P_pad = _round_up(P, pad_partitions_to)
-    d = items.shape[1]
-    owned_lists = [decoded[i] for i in owned_idx]
-    bucket_lists = [decoded[np.concatenate(parts)] for parts in bucket_idx]
-    empty = np.zeros((0, d), np.float32)
-    owned_lists += [empty] * (P_pad - P)
-    bucket_lists += [empty] * (P_pad - P)
-    C1 = _round_up(max(len(o) for o in owned_lists), tile)
-    C2 = _round_up(max(len(b) for b in bucket_lists), tile)
-    sd = ShuffledData(
-        owned=np.stack([_pad_rows(o, C1, pad_value) for o in owned_lists]),
-        bucket=np.stack([_pad_rows(b, C2, pad_value) for b in bucket_lists]),
-        n_owned=np.array([len(o) for o in owned_lists], np.int32),
-        n_bucket=np.array([len(b) for b in bucket_lists], np.int32),
-    )
-    n_shuffled = int(sd.n_bucket.sum())
-    t1 = time.perf_counter()
+    with tr.span("shuffle", cat="stage", engine="host"):
+        t0 = time.perf_counter()
+        decoded = codec.roundtrip(items).astype(np.float32)
+        P_pad = _round_up(P, pad_partitions_to)
+        d = items.shape[1]
+        owned_lists = [decoded[i] for i in owned_idx]
+        bucket_lists = [decoded[np.concatenate(parts)]
+                        for parts in bucket_idx]
+        empty = np.zeros((0, d), np.float32)
+        owned_lists += [empty] * (P_pad - P)
+        bucket_lists += [empty] * (P_pad - P)
+        C1 = _round_up(max(len(o) for o in owned_lists), tile)
+        C2 = _round_up(max(len(b) for b in bucket_lists), tile)
+        sd = ShuffledData(
+            owned=np.stack([_pad_rows(o, C1, pad_value)
+                            for o in owned_lists]),
+            bucket=np.stack([_pad_rows(b, C2, pad_value)
+                             for b in bucket_lists]),
+            n_owned=np.array([len(o) for o in owned_lists], np.int32),
+            n_bucket=np.array([len(b) for b in bucket_lists], np.int32),
+        )
+        n_shuffled = int(sd.n_bucket.sum())
+        t1 = time.perf_counter()
     stats.shuffle_wall_s = t1 - t0
-    if tr.enabled:
-        tr.record("shuffle", t0, t1, cat="stage", engine="host")
     stats.shuffle_wire_bytes = codec.nbytes(n_shuffled * d)
     stats.shuffle_raw_bytes = 4 * n_shuffled * d
     stats.n_items = len(items)
@@ -870,27 +871,30 @@ class ResidentCatalog:
         accumulate (``+=``) stats contract. Decode happens on-device per
         pass; under a data-axis mesh each tier reduces psum-sharded."""
         D = _data_axis_size(self.mesh)
-        t0 = time.perf_counter()
-        totals = None
-        for tier in self.sd.tiers:
-            if D > 1:
-                outs = _reduce_tier_sharded(reducers, self.codec, tier,
-                                            self.mesh)
-            else:
-                owned = self.codec.decode_device(*tier.owned_wire)
-                bucket = self.codec.decode_device(*tier.bucket_wire)
-                outs = tuple(r.reduce_partitions(owned, bucket, tier.n_owned,
-                                                 tier.n_bucket)
-                             for r in reducers)
-            totals = outs if totals is None else tuple(
-                jax.tree.map(jnp.add, a, b) for a, b in zip(totals, outs))
-        totals = jax.block_until_ready(totals)
-        t1 = time.perf_counter()
-        stats.reduce_wall_s += t1 - t0
         tr = get_tracer()
-        if tr.enabled:
-            tr.record("reduce", t0, t1, cat="stage", engine="device",
-                      tiers=len(self.sd.tiers))
+        with tr.span("reduce", cat="stage", engine="device",
+                     tiers=len(self.sd.tiers)):
+            t0 = time.perf_counter()
+            totals = None
+            with tr.span("reduce.dispatch", cat="stage"):
+                for tier in self.sd.tiers:
+                    if D > 1:
+                        outs = _reduce_tier_sharded(reducers, self.codec,
+                                                    tier, self.mesh)
+                    else:
+                        owned = self.codec.decode_device(*tier.owned_wire)
+                        bucket = self.codec.decode_device(*tier.bucket_wire)
+                        outs = tuple(r.reduce_partitions(owned, bucket,
+                                                         tier.n_owned,
+                                                         tier.n_bucket)
+                                     for r in reducers)
+                    totals = outs if totals is None else tuple(
+                        jax.tree.map(jnp.add, a, b)
+                        for a, b in zip(totals, outs))
+            with tr.span("reduce.wait", cat="stage"):
+                totals = jax.block_until_ready(totals)
+            t1 = time.perf_counter()
+        stats.reduce_wall_s += t1 - t0
         stats.reduce_bytes += self.nbytes
         flops = float(sum(r.flops(self.sd) for r in reducers))
         stats.reduce_flops += flops
@@ -951,70 +955,79 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
     from repro.core.cost_model import StageCost, get_cost_model
     D = _data_axis_size(mesh)
     d = m.d
-    t0 = time.perf_counter()
-    keys_h = np.asarray(jax.block_until_ready(m.keys))
-    dest_h = np.asarray(m.dest_eff)
-    # keys == P marks payload-only rows (carried for the bucket entries that
-    # reference them — spilled range reads use this for cross-range border
-    # rows); like dest == P they are excluded from owned counts/scatter.
-    n_owned = np.bincount(keys_h, minlength=P + 1)[:P].astype(np.int64)
-    n_bucket = np.bincount(dest_h, minlength=P + 1)[:P].astype(np.int64)
-    tile_req = tile
-    if tile == "auto":
-        tile, plan, _ = get_cost_model().plan_shuffle(n_owned, n_bucket, D,
-                                                      d=d, basis=cost_basis)
-        stats.auto_tile = int(tile)
-    else:
-        plan = plan_tiers(n_owned, n_bucket, tile, pad_partitions_to=D)
-    part_tier = np.full(P + 1, -1, np.int32)
-    part_local = np.zeros(P + 1, np.int32)
-    specs = []
-    for t, (ids, C1, C2) in enumerate(plan):
-        part_tier[ids] = t
-        part_local[ids] = np.arange(len(ids), dtype=np.int32)
-        specs.append((_round_up(len(ids), D), C1, C2))
-    o_starts = np.zeros(P + 1, np.int32)
-    np.cumsum(n_owned, out=o_starts[1:])
-    b_starts = np.zeros(P + 1, np.int32)
-    np.cumsum(n_bucket, out=b_starts[1:])
-    stats.shuffle_index_impl = "jnp" if _use_jnp_indices() else "host"
-    if _use_jnp_indices():
-        scattered = _scatter_tiers_jit(
-            m.payloads, m.keys, m.dest_eff, m.src,
-            jnp.asarray(o_starts), jnp.asarray(b_starts),
-            jnp.asarray(part_tier), jnp.asarray(part_local),
-            specs=tuple(specs))
-    else:
-        src_h = np.asarray(m.src)
-        live = dest_h < P           # drop non-replicated border slots before
-        if not live.all():          # sorting: fewer copies = less sort work
-            dest_h, src_h = dest_h[live], src_h[live]
-        scattered = _scatter_tiers_host(
-            m.payloads, keys_h, dest_h, src_h,
-            None if m.skey is None else np.asarray(m.skey), o_starts,
-            b_starts, part_tier, part_local, tuple(specs))
-    scattered = jax.block_until_ready(scattered)
-    tiers = []
-    shard_pad = np.zeros(D, np.float64)
-    shard_real = np.zeros(D, np.float64)
-    for ((ids, C1, C2), (Pt, _, _), (own, bkt)) in zip(plan, specs, scattered):
-        no_t = np.zeros(Pt, np.int64)
-        nb_t = np.zeros(Pt, np.int64)
-        no_t[:len(ids)] = n_owned[ids]
-        nb_t[:len(ids)] = n_bucket[ids]
-        tiers.append(TierData(ids, own, bkt, jnp.asarray(no_t, jnp.int32),
-                              jnp.asarray(nb_t, jnp.int32), C1=C1, C2=C2,
-                              Pt=Pt))
-        shard_real += (no_t * nb_t).reshape(D, Pt // D).sum(axis=1)
-        shard_pad += float(Pt // D) * C1 * C2
-    sd = DeviceShuffledData(tiers, n_owned, n_bucket)
-    n_shuffled = int(n_bucket.sum())
-    wire = n_shuffled * codec.device_bytes_per_item(d)
-    t1 = time.perf_counter()
-    stats.shuffle_wall_s += t1 - t0
     tr = get_tracer()
-    if tr.enabled:
-        tr.record("shuffle", t0, t1, cat="stage", engine="device")
+    with tr.span("shuffle", cat="stage", engine="device"):
+        t0 = time.perf_counter()
+        with tr.span("shuffle.wait", cat="stage"):
+            keys_h = np.asarray(jax.block_until_ready(m.keys))
+            dest_h = np.asarray(m.dest_eff)
+        with tr.span("shuffle.plan", cat="stage"):
+            # keys == P marks payload-only rows (carried for the bucket
+            # entries that reference them — spilled range reads use this for
+            # cross-range border rows); like dest == P they are excluded
+            # from owned counts/scatter.
+            n_owned = np.bincount(keys_h, minlength=P + 1)[:P].astype(
+                np.int64)
+            n_bucket = np.bincount(dest_h, minlength=P + 1)[:P].astype(
+                np.int64)
+            tile_req = tile
+            if tile == "auto":
+                tile, plan, _ = get_cost_model().plan_shuffle(
+                    n_owned, n_bucket, D, d=d, basis=cost_basis)
+                stats.auto_tile = int(tile)
+            else:
+                plan = plan_tiers(n_owned, n_bucket, tile,
+                                  pad_partitions_to=D)
+            part_tier = np.full(P + 1, -1, np.int32)
+            part_local = np.zeros(P + 1, np.int32)
+            specs = []
+            for t, (ids, C1, C2) in enumerate(plan):
+                part_tier[ids] = t
+                part_local[ids] = np.arange(len(ids), dtype=np.int32)
+                specs.append((_round_up(len(ids), D), C1, C2))
+            o_starts = np.zeros(P + 1, np.int32)
+            np.cumsum(n_owned, out=o_starts[1:])
+            b_starts = np.zeros(P + 1, np.int32)
+            np.cumsum(n_bucket, out=b_starts[1:])
+        stats.shuffle_index_impl = "jnp" if _use_jnp_indices() else "host"
+        with tr.span("shuffle.scatter", cat="stage"):
+            if _use_jnp_indices():
+                scattered = _scatter_tiers_jit(
+                    m.payloads, m.keys, m.dest_eff, m.src,
+                    jnp.asarray(o_starts), jnp.asarray(b_starts),
+                    jnp.asarray(part_tier), jnp.asarray(part_local),
+                    specs=tuple(specs))
+            else:
+                src_h = np.asarray(m.src)
+                live = dest_h < P     # drop non-replicated border slots
+                if not live.all():    # before sorting: fewer copies = less
+                    dest_h, src_h = dest_h[live], src_h[live]   # sort work
+                scattered = _scatter_tiers_host(
+                    m.payloads, keys_h, dest_h, src_h,
+                    None if m.skey is None else np.asarray(m.skey),
+                    o_starts, b_starts, part_tier, part_local, tuple(specs))
+            scattered = jax.block_until_ready(scattered)
+        with tr.span("shuffle.tiers", cat="stage"):
+            tiers = []
+            shard_pad = np.zeros(D, np.float64)
+            shard_real = np.zeros(D, np.float64)
+            for ((ids, C1, C2), (Pt, _, _), (own, bkt)) in zip(
+                    plan, specs, scattered):
+                no_t = np.zeros(Pt, np.int64)
+                nb_t = np.zeros(Pt, np.int64)
+                no_t[:len(ids)] = n_owned[ids]
+                nb_t[:len(ids)] = n_bucket[ids]
+                tiers.append(TierData(ids, own, bkt,
+                                      jnp.asarray(no_t, jnp.int32),
+                                      jnp.asarray(nb_t, jnp.int32), C1=C1,
+                                      C2=C2, Pt=Pt))
+                shard_real += (no_t * nb_t).reshape(D, Pt // D).sum(axis=1)
+                shard_pad += float(Pt // D) * C1 * C2
+            sd = DeviceShuffledData(tiers, n_owned, n_bucket)
+        n_shuffled = int(n_bucket.sum())
+        wire = n_shuffled * codec.device_bytes_per_item(d)
+        t1 = time.perf_counter()
+    stats.shuffle_wall_s += t1 - t0
     stats.shuffle_wire_bytes += wire
     stats.shuffle_raw_bytes += 4 * n_shuffled * d
     # predicted shuffle wall: the sort/scatter is byte-bound — payload rows
@@ -1193,14 +1206,12 @@ def host_shuffle_reduce(jobs, items, stats: StageStats, mesh=None):
     cells = (sd.n_owned.astype(np.float64)
              * sd.n_bucket).reshape(D, q).sum(axis=1)
     pad_cells = float(q) * sd.owned.shape[1] * sd.bucket.shape[1]
-    t0 = time.perf_counter()
-    totals = jax.block_until_ready(
-        reduce_stage([j.reducer for j in jobs], sd, mesh))
-    t1 = time.perf_counter()
+    with get_tracer().span("reduce", cat="stage", engine="host"):
+        t0 = time.perf_counter()
+        totals = jax.block_until_ready(
+            reduce_stage([j.reducer for j in jobs], sd, mesh))
+        t1 = time.perf_counter()
     stats.reduce_wall_s += t1 - t0
-    tr = get_tracer()
-    if tr.enabled:
-        tr.record("reduce", t0, t1, cat="stage", engine="host")
     stats.reduce_bytes += sd.owned.nbytes + sd.bucket.nbytes
     stats.reduce_flops += float(sum(j.reducer.flops(sd) for j in jobs))
     return totals, sd, np.full(D, pad_cells), np.asarray(cells, np.float64)

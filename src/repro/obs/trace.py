@@ -15,11 +15,26 @@ Export targets:
   in Perfetto or chrome://tracing. Timestamps are microseconds relative
   to tracer construction.
 - ``summary()``: a per-span-name text table (count / total / mean / max).
+- the profiler: while a ``jax.profiler`` session records (for instance
+  inside ``jax.profiler.trace(dir)``), every ``span()`` of either tracer
+  also opens a ``jax.profiler.TraceAnnotation`` named ``mr:<span name>``,
+  so the program's spans land in the session's ``.xplane.pb`` as host
+  events on the profiler's own clock, beside the device ops.
+
+Compile counters: each span opened by an enabled ``Tracer`` or under a
+profiler session counts JAX's compile-pipeline events (``jax.monitoring``)
+that its thread runs while it is open, nested spans inclusively, and
+carries them on exit: ``jax_traces``, ``jax_lowerings``, ``jax_cache_hits``
+(persistent-cache reads), ``jax_compiles`` (backend compiles the cache did
+not serve) and ``jax_compile_s`` (the three durations summed, cache reads
+included). They go to the profiler event's stats always, and to the Chrome
+event's ``args`` where any is non-zero. The listener is registered the
+first time such a span opens, and never in a run without one.
 
 The module-level current tracer defaults to ``NullTracer`` whose
 ``span()`` / ``ids()`` return a shared reentrant no-op context manager,
-so instrumented hot paths cost one attribute lookup and one method call
-when tracing is off.
+so instrumented hot paths cost one attribute lookup, one method call and
+one ``TraceAnnotation.is_enabled()`` when tracing and profiling are off.
 
 Spans close in a ``finally`` block, so an exception thrown mid-stage (a
 chaos-killed lane, a cancelled clone) still closes every opened span —
@@ -33,6 +48,110 @@ import os
 import threading
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+# True while a jax.profiler session records (one C++ call)
+_profiling = TraceAnnotation.is_enabled
+
+PREFIX = "mr:"                  # profiler event name = PREFIX + span name
+COUNTERS = ("jax_traces", "jax_lowerings", "jax_cache_hits", "jax_compiles",
+            "jax_compile_s")
+_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_traces",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lowerings",
+    "/jax/core/compile/backend_compile_duration": "jax_compiles",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class _CompileCounts:
+    """JAX's compile-pipeline events, counted into every span open on the
+    thread that runs them. A persistent-cache hit is reported just before
+    the backend-compile event it stands for, so that event counts as a hit,
+    not as a compile."""
+
+    def __init__(self):
+        self._tls = threading.local()
+        self._lock = threading.Lock()
+        self.registered = False
+
+    def _frames(self) -> List[Dict[str, float]]:
+        frames = getattr(self._tls, "frames", None)
+        if frames is None:
+            frames = self._tls.frames = []
+        return frames
+
+    def open(self) -> Dict[str, float]:
+        if not self.registered:
+            self._register()
+        counts = dict.fromkeys(COUNTERS, 0)
+        counts["jax_compile_s"] = 0.0
+        self._frames().append(counts)
+        return counts
+
+    def close(self, counts: Dict[str, float]) -> None:
+        frames = self._frames()
+        if frames and frames[-1] is counts:
+            frames.pop()
+        else:                       # spans closed out of nesting order
+            frames[:] = [f for f in frames if f is not counts]
+
+    def _register(self) -> None:
+        import jax
+        with self._lock:
+            if self.registered:
+                return
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+            jax.monitoring.register_event_listener(self._on_event)
+            self.registered = True
+
+    def _on_duration(self, event: str, duration: float, **_) -> None:
+        key = _DURATION_EVENTS.get(event)
+        if key is None:
+            return
+        if key == "jax_compiles" and getattr(self._tls, "hit", False):
+            self._tls.hit = False
+            key = None              # a cache read: counted in jax_cache_hits
+        for counts in getattr(self._tls, "frames", ()):
+            counts["jax_compile_s"] += duration
+            if key is not None:
+                counts[key] += 1
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == _CACHE_HIT_EVENT:
+            self._tls.hit = True
+            for counts in getattr(self._tls, "frames", ()):
+                counts["jax_cache_hits"] += 1
+
+
+_COUNTS = _CompileCounts()
+
+
+class _ProfiledSpan:
+    """What every span shares: the compile counters, and under a profiler
+    session a ``mr:<name>`` annotation that carries them as its stats."""
+
+    __slots__ = ("name", "counts", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> Dict[str, float]:
+        self.counts = _COUNTS.open()
+        self._ann = None
+        if _profiling():
+            self._ann = TraceAnnotation(PREFIX + self.name)
+            self._ann.__enter__()
+        return self.counts
+
+    def __exit__(self, *exc):
+        _COUNTS.close(self.counts)
+        if self._ann is not None:
+            self._ann.set_metadata(**self.counts)
+            self._ann.__exit__(*exc)
+        return False
 
 
 class _NullCtx:
@@ -51,12 +170,15 @@ _NULL_CTX = _NullCtx()
 
 
 class NullTracer:
-    """Disabled tracer: every call is a no-op returning shared objects."""
+    """Disabled tracer: every call is a no-op returning shared objects,
+    except ``span()`` under a profiler session, which still annotates."""
 
     enabled = False
 
-    def span(self, name: str, cat: str = "stage", **ids) -> _NullCtx:
-        return _NULL_CTX
+    def span(self, name: str, cat: str = "stage", **ids):
+        if not _profiling():
+            return _NULL_CTX
+        return _ProfiledSpan(name)
 
     def ids(self, **ids) -> _NullCtx:
         return _NULL_CTX
@@ -141,6 +263,8 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "stage", **ids) -> Iterator[None]:
         """Record a complete span around the with-body (closes in finally)."""
+        prof = _ProfiledSpan(name)
+        counts = prof.__enter__()
         t0 = self._clock()
         with self._lock:
             self._opened += 1
@@ -148,7 +272,10 @@ class Tracer:
             yield
         finally:
             t1 = self._clock()
+            prof.__exit__(None, None, None)
             ev = self._event(name, cat, "X", t0, t1 - t0, ids)
+            if any(counts.values()):
+                ev["args"].update(counts)
             with self._lock:
                 self._closed += 1
                 self.events.append(ev)

@@ -17,8 +17,14 @@ Three contracts, tested in isolation and threaded through the runtime:
 Plus the ``latency_summary`` degenerate-span edges (a single request
 must not report ~1e9 qps) fixed alongside this layer.
 """
+import glob
+import importlib.util
 import json
+import os
+import subprocess
+import sys
 import threading
+from pathlib import Path
 
 import pytest
 
@@ -26,14 +32,17 @@ from repro.data import sky
 from repro.data.pipeline import ArraySplits
 from repro.ft import LaneChaos
 from repro.mapreduce import (RequestStats, ZonePartitioner, latency_summary,
-                             neighbor_search_job, run_job, run_job_streaming)
+                             neighbor_search_job, run_job, run_job_streaming,
+                             run_jobs)
 from repro.mapreduce.instrumentation import StageStats
 from repro.obs import (ATOM_HOST, BLADE_DEVICE, MetricsRegistry, ModeledMeter,
                        NullTracer, NvmlMeter, RaplMeter, Tracer, get_meter,
                        get_tracer, pick_meter, use_meter, use_tracer)
+from repro.obs import trace as trace_mod
 from repro.serving import MRQueryService
 
 RADIUS = 0.02
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def _catalog(n=3000, seed=0):
@@ -185,6 +194,148 @@ def test_null_tracer_is_reentrant_noop():
         tr.record("d", 0.0, 1.0)
     assert tr.events == () and tr.open_spans == 0 and not tr.enabled
     assert isinstance(get_tracer(), NullTracer)  # module default stays null
+
+
+# ---------------------------------------------------------------------------
+# Profiler sink and compile counters
+# ---------------------------------------------------------------------------
+
+DEVICE_SPANS = {"mr:job", "mr:map", "mr:shuffle", "mr:shuffle.wait",
+                "mr:shuffle.plan", "mr:shuffle.scatter", "mr:shuffle.tiers",
+                "mr:reduce", "mr:reduce.dispatch", "mr:reduce.wait"}
+
+
+def _profiled_spans(trace_dir):
+    """``{name: [(start_ns, end_ns, stats), ...]}`` of the ``mr:*`` host
+    events of the one ``.xplane.pb`` under ``trace_dir``."""
+    import jax
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(trace_mod.PREFIX):
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+    return out
+
+
+def test_profiler_session_records_device_job_spans(tmp_path):
+    """Under a ``jax.profiler`` session and the default ``NullTracer``, a
+    device-engine job writes every seam's span into the ``.xplane.pb``,
+    nested inside ``mr:job`` and carrying the compile counters."""
+    import jax
+    xyz = _catalog(2345, seed=4)     # a catalog size of its own: it compiles
+    job = neighbor_search_job(RADIUS, tile=128)
+    with jax.profiler.trace(str(tmp_path)):
+        res = run_jobs([job], xyz, engine="device")
+    assert res[0].output == run_job(job, xyz, engine="host").output
+    spans = _profiled_spans(str(tmp_path))
+    assert set(spans) == DEVICE_SPANS
+    ((j0, j1, job_stats),) = spans["mr:job"]
+    for name, evs in spans.items():
+        for s, e, stats in evs:
+            assert j0 <= s <= e <= j1, name
+            assert set(stats) == set(trace_mod.COUNTERS), name
+            assert stats["jax_compile_s"] <= job_stats["jax_compile_s"]
+    for parent in ("mr:shuffle", "mr:reduce"):
+        ((p0, p1, _),) = spans[parent]
+        for name in DEVICE_SPANS:
+            if name.startswith(parent + "."):
+                assert all(p0 <= s <= e <= p1 for s, e, _ in spans[name])
+    # the new shapes were traced, lowered and compiled inside the job
+    assert job_stats["jax_traces"] > 0 and job_stats["jax_lowerings"] > 0
+    assert job_stats["jax_compiles"] + job_stats["jax_cache_hits"] > 0
+    assert job_stats["jax_compile_s"] > 0
+
+
+def test_compile_counters_nest_and_tell_cache_hits_from_compiles():
+    import jax
+    trace_dur = "/jax/core/compile/jaxpr_trace_duration"
+    lower_dur = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    compile_dur = "/jax/core/compile/backend_compile_duration"
+    tr = Tracer()
+    other = []
+    with tr.span("outer"):
+        jax.monitoring.record_event_duration_secs(trace_dur, 0.5)
+        with tr.span("inner"):
+            jax.monitoring.record_event_duration_secs(lower_dur, 0.25)
+            jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+            jax.monitoring.record_event_duration_secs(compile_dur, 0.125)
+            jax.monitoring.record_event_duration_secs(compile_dur, 1.0)
+        # another thread's events count in that thread's spans only
+        t = threading.Thread(target=lambda: other.append(
+            jax.monitoring.record_event_duration_secs(trace_dur, 8.0)))
+        t.start()
+        t.join()
+    with tr.span("quiet"):
+        pass
+    evs = {e["name"]: e["args"] for e in tr.events}
+    assert evs["inner"] == {"jax_traces": 0, "jax_lowerings": 1,
+                            "jax_cache_hits": 1, "jax_compiles": 1,
+                            "jax_compile_s": 1.375}
+    assert evs["outer"] == {"jax_traces": 1, "jax_lowerings": 1,
+                            "jax_cache_hits": 1, "jax_compiles": 1,
+                            "jax_compile_s": 1.875}
+    assert evs["quiet"] == {}            # no compile event, args as before
+
+
+def test_no_profiler_and_null_tracer_register_no_listener():
+    """A run with neither a profiler session nor an enabled ``Tracer``
+    records nothing and never registers a ``jax.monitoring`` listener."""
+    code = (
+        "import jax\n"
+        "from jax._src import monitoring\n"
+        "from repro.data import sky\n"
+        "from repro.mapreduce import neighbor_search_job, run_jobs\n"
+        "from repro.obs import get_tracer, trace\n"
+        "xyz = sky.make_catalog(1500, seed=1)\n"
+        "run_jobs([neighbor_search_job(0.02, tile=128)], xyz,\n"
+        "         engine='device')\n"
+        "cbs = (monitoring.get_event_duration_listeners()\n"
+        "       + monitoring.get_event_listeners())\n"
+        "print(trace._COUNTS.registered,\n"
+        "      any(getattr(c, '__self__', None) is trace._COUNTS\n"
+        "          for c in cbs),\n"
+        "      get_tracer().events)\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.pathsep.join(
+               [str(ROOT / "src"), os.environ.get("PYTHONPATH", "")])}
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == ["False", "False", "()"]
+
+
+def test_tracer_chrome_export_keeps_stage_spans_beside_profiler(tmp_path):
+    """An enabled ``Tracer`` still exports the stage spans as Chrome JSON,
+    and under a profiler session its spans reach the ``.xplane.pb`` too."""
+    import jax
+    xyz = _catalog(2222, seed=5)
+    with use_tracer(Tracer()) as tr, jax.profiler.trace(str(tmp_path)):
+        run_jobs([neighbor_search_job(RADIUS, tile=128)], xyz,
+                 engine="device")
+    assert tr.open_spans == 0
+    doc = json.loads(tr.export_json())
+    evs = {e["name"]: e for e in doc["traceEvents"]}
+    assert {n[len(trace_mod.PREFIX):] for n in DEVICE_SPANS} <= set(evs)
+    assert evs["job"]["args"]["mode"] == "stream"
+    assert evs["job"]["args"]["jax_traces"] > 0
+    assert set(_profiled_spans(str(tmp_path))) == DEVICE_SPANS
+
+
+def test_export_trace_script_passes(tmp_path, capsys):
+    spec = importlib.util.spec_from_file_location(
+        "export_trace", ROOT / "scripts" / "export_trace.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    out = tmp_path / "trace.json"
+    assert mod.main(str(out)) == 0
+    names = {e["name"] for e in json.loads(out.read_text())["traceEvents"]}
+    assert mod.REQUIRED_SPANS <= names
+    assert "reduce.dispatch" in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------------
