@@ -23,8 +23,20 @@ edges) live in SMEM. Each partition accumulates its cumulative per-edge
 counts in ONE resident lane-dense ``(R, 128)`` int32 block across its
 ``(i, j)`` steps (edge ``k`` at row ``k // 128``, lane ``k % 128``), so the
 output is ``P`` small tiles, not one per grid step.
+
+Dispatch: the ``pl.pallas_call`` of a tier shape ``(P, M, N, d, nbins)``
+is built once (``_hist_call``) and Pallas jits it, so the first call of a
+shape traces, lowers and compiles the kernel and every later call
+dispatches the cached executable through jit's C++ cache. The operand
+casts, the transpose of ``b`` and the closing sum are eager jnp calls,
+cached the same way. They stay outside any ``jax.jit`` of this module:
+XLA names a custom call after its innermost enclosing jit (Pallas's own
+excepted), and a profile finds this kernel by its target name,
+``tpu_custom_call``.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -76,18 +88,14 @@ def _hist_masked_kernel(na_ref, nb_ref, edges_ref, a_ref, bt_ref, o_ref):
         o_ref[0] = acc
 
 
-def pair_hist_masked_pallas(a, b, n_a, n_b, cos_edges, *, tm: int = TM,
-                            tn: int = TN, interpret: bool = False):
-    """a: [P,M,d], b: [P,N,d] (any float dtype), n_a/n_b: [P] int32 real
-    counts, cos_edges: [NB]. -> [NB] int32 cumulative counts
-    ``#{valid (p,i,j): a[p,i] . b[p,j] >= cos_edges[k]}``."""
-    P, M, d = a.shape
-    N = b.shape[1]
-    tm, tn = _fit_tile(M, tm), _fit_tile(N, tn)
-    nbins = cos_edges.shape[0]
+@functools.lru_cache(maxsize=None)
+def _hist_call(P: int, M: int, N: int, d: int, nbins: int, tm: int, tn: int,
+               interpret: bool):
+    """The kernel's Pallas call for one tier shape; the same object, and so
+    the same compiled program, serves every later call of that shape."""
     rows = 8 * max(1, -(-nbins // (8 * _LANES)))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _hist_masked_kernel,
         grid=(P, M // tm, N // tn),
         in_specs=[smem, smem, smem,
@@ -98,8 +106,21 @@ def pair_hist_masked_pallas(a, b, n_a, n_b, cos_edges, *, tm: int = TM,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray(n_a, jnp.int32), jnp.asarray(n_b, jnp.int32),
-      jnp.asarray(cos_edges, jnp.float32), a, jnp.swapaxes(b, 1, 2))
+    )
+
+
+def pair_hist_masked_pallas(a, b, n_a, n_b, cos_edges, *, tm: int = TM,
+                            tn: int = TN, interpret: bool = False):
+    """a: [P,M,d], b: [P,N,d] (any float dtype), n_a/n_b: [P] int32 real
+    counts, cos_edges: [NB]. -> [NB] int32 cumulative counts
+    ``#{valid (p,i,j): a[p,i] . b[p,j] >= cos_edges[k]}``."""
+    P, M, d = a.shape
+    N = b.shape[1]
+    nbins = cos_edges.shape[0]
+    call = _hist_call(P, M, N, d, nbins, _fit_tile(M, tm), _fit_tile(N, tn),
+                      interpret)
+    out = call(jnp.asarray(n_a, jnp.int32), jnp.asarray(n_b, jnp.int32),
+               jnp.asarray(cos_edges, jnp.float32), a, jnp.swapaxes(b, 1, 2))
     return jnp.sum(out, axis=0, dtype=jnp.int32).reshape(-1)[:nbins]
 
 

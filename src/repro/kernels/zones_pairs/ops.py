@@ -10,8 +10,10 @@ via n_a/n_b, never via pad-value tricks. On TPU: the Pallas kernels with a
 leading partition grid axis. Elsewhere: the z-banded blocked reduce
 (``blocked.py``) — same results, tile pairs outside the z band pruned,
 fixed-shape chunks so the XLA compile is shared across codecs, radii, and
-job shapes. These run eagerly (the blocked path plans its blocks on the
-host), NOT under jit.
+job shapes. The blocked path runs eagerly (it plans its blocks on the
+host), NOT under jit. The Pallas path builds one Pallas call per tier
+shape and keeps it (``kernel._hist_call``), so it traces and lowers once
+per shape and each later call of that shape dispatches a cached program.
 
 Traceability: the Pallas variants are pure traced jax and can run inside a
 ``shard_map`` region (the mesh-sharded device reduce; interpret mode

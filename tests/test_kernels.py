@@ -162,6 +162,48 @@ def test_pair_hist_masked_ragged(P, C1, C2, n_o, n_b, nbins):
     np.testing.assert_array_equal(np.asarray(got_blk, np.int64), want)
 
 
+def _masked_pallas_and_ref(kind, a, b, no, nb):
+    from repro.kernels.zones_pairs.ref import (pair_count_masked_ref,
+                                               pair_hist_masked_ref)
+    if kind == "count":
+        cmin = float(np.cos(0.3))
+        return (lambda: pair_count_masked_pallas(a, b, no, nb, cmin, tm=32,
+                                                 tn=32, interpret=True),
+                pair_count_masked_ref(a, b, no, nb, cmin))
+    edges = jnp.asarray(np.cos(np.linspace(0.05, 0.4, 5)), jnp.float32)
+    return (lambda: pair_hist_masked_pallas(a, b, no, nb, edges, tm=32,
+                                            tn=32, interpret=True),
+            pair_hist_masked_ref(a, b, no, nb, edges))
+
+
+@pytest.mark.parametrize("kind", ["hist", "count"])
+@pytest.mark.parametrize("new_shape", [
+    (5, 96, 160, (1, 96, 0, 40, 7), (3, 160, 0, 99, 160)),    # a new P
+    (3, 96, 224, (96, 0, 17), (224, 9, 0)),                   # a new N
+])
+def test_masked_pallas_traces_once_per_shape(kind, new_shape):
+    """The masked kernel wrappers trace and lower a tier shape once: a
+    second call of that shape (other rows, other counts) runs no trace and
+    no lowering, and a new partition count or capacity traces anew. Every
+    call stays bit-identical to the masked reference."""
+    from repro.obs import Tracer
+    calls = [(3, 96, 160, (96, 5, 0), (160, 1, 77), 0),
+             (3, 96, 160, (20, 96, 3), (0, 160, 42), 11),
+             (*new_shape, 23)]
+    tr = Tracer()
+    for i, (P, C1, C2, n_o, n_b, seed) in enumerate(calls):
+        run, want = _masked_pallas_and_ref(
+            kind, *_masked_case(P, C1, C2, n_o, n_b, seed=seed))
+        with tr.span(f"call{i}"):
+            got = run()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    counts = {e["name"]: e["args"] for e in tr.events}
+    assert counts["call1"].get("jax_traces", 0) == 0, counts["call1"]
+    assert counts["call1"].get("jax_lowerings", 0) == 0, counts["call1"]
+    assert counts["call2"]["jax_traces"] > 0, counts["call2"]
+    assert counts["call2"]["jax_lowerings"] > 0, counts["call2"]
+
+
 def test_blocked_prunes_but_counts_exactly():
     """The z-banded blocked reduce must skip tile pairs (on a z-sorted
     catalog spanning the sphere) yet return exactly the dense masked

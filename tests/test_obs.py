@@ -26,14 +26,15 @@ import sys
 import threading
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from repro.data import sky
 from repro.data.pipeline import ArraySplits
 from repro.ft import LaneChaos
 from repro.mapreduce import (RequestStats, ZonePartitioner, latency_summary,
-                             neighbor_search_job, run_job, run_job_streaming,
-                             run_jobs)
+                             neighbor_search_job, neighbor_statistics_job,
+                             run_job, run_job_streaming, run_jobs)
 from repro.mapreduce.instrumentation import StageStats
 from repro.obs import (ATOM_HOST, BLADE_DEVICE, MetricsRegistry, ModeledMeter,
                        NullTracer, NvmlMeter, RaplMeter, Tracer, get_meter,
@@ -307,6 +308,27 @@ def test_no_profiler_and_null_tracer_register_no_listener():
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr
     assert out.stdout.split() == ["False", "False", "()"]
+
+
+def test_second_pallas_job_dispatches_cached_reduce_programs():
+    """Two jobs over one catalog: the first traces and lowers the Pallas
+    reduce once per tier shape, the second's ``reduce.dispatch`` span runs
+    no trace and no lowering, and both histograms equal the host engine's."""
+    xyz = _catalog(2718, seed=6)     # a catalog size of its own: it compiles
+    edges = np.linspace(0.01, 0.05, 4) / sky.ARCSEC
+    job = neighbor_statistics_job(edges, tile=64, use_pallas=True)
+    want = run_job(neighbor_statistics_job(edges, tile=64), xyz,
+                   engine="host").output
+    with use_tracer(Tracer()) as tr:
+        outs = [run_jobs([job], xyz, engine="device")[0].output
+                for _ in range(2)]
+    for out in outs:
+        np.testing.assert_array_equal(out, want)
+    first, second = [e["args"] for e in tr.events
+                     if e["name"] == "reduce.dispatch"]
+    assert first["jax_traces"] > 0 and first["jax_lowerings"] > 0
+    assert second.get("jax_traces", 0) == 0, second
+    assert second.get("jax_lowerings", 0) == 0, second
 
 
 def test_tracer_chrome_export_keeps_stage_spans_beside_profiler(tmp_path):

@@ -7,13 +7,16 @@ kernels at engine shapes (``tile=256``, capacities up to 8192) and one
 4-device sharded count + ``psum`` for a described ``v5e:2x2``. Nothing
 runs, so they say nothing about results or times.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.kernels.zones_pairs.kernel import (pair_count_masked_pallas,
+from repro.kernels.zones_pairs.kernel import (_hist_call,
+                                              pair_count_masked_pallas,
                                               pair_hist_masked_pallas)
 
 
@@ -70,6 +73,20 @@ def test_masked_kernels_compile_for_v5e(topo, no_persistent_cache, P_, C1,
         assert compiled.out_info.shape == ()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4 << 30
+
+
+def test_pair_kernel_keeps_its_profile_name(topo, no_persistent_cache):
+    """A device profile finds the pair kernel by the name of its custom
+    call: the program each tier dispatches names it ``tpu_custom_call``."""
+    one = SingleDeviceSharding(topo.devices[0])
+    P_, C1, C2, nbins = 4, 1024, 2048, 16
+    n = jax.ShapeDtypeStruct((P_,), jnp.int32, sharding=one)
+    text = _hist_call(P_, C1, C2, 3, nbins, 256, 256, False).lower(
+        n, n, jax.ShapeDtypeStruct((nbins,), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((P_, C1, 3), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((P_, 3, C2), jnp.float32, sharding=one),
+    ).compile().as_text()
+    assert re.search(r"%tpu_custom_call[.\d]* = [^\n]*custom-call", text)
 
 
 def test_sharded_count_psum_compiles_for_v5e_2x2(topo, no_persistent_cache):
