@@ -28,7 +28,7 @@ Dispatch: the ``pl.pallas_call`` of a tier shape ``(P, M, N, d, nbins)``
 is built once (``_hist_call``) and Pallas jits it, so the first call of a
 shape traces, lowers and compiles the kernel and every later call
 dispatches the cached executable through jit's C++ cache. The operand
-casts, the transpose of ``b`` and the closing sum are eager jnp calls,
+casts, the transpose of ``b`` and the closing reshape are eager jnp calls,
 cached the same way. They stay outside any ``jax.jit`` of this module:
 XLA names a custom call after its innermost enclosing jit (Pallas's own
 excepted), and a profile finds this kernel by its target name,
@@ -112,8 +112,10 @@ def _hist_call(P: int, M: int, N: int, d: int, nbins: int, tm: int, tn: int,
 def pair_hist_masked_pallas(a, b, n_a, n_b, cos_edges, *, tm: int = TM,
                             tn: int = TN, interpret: bool = False):
     """a: [P,M,d], b: [P,N,d] (any float dtype), n_a/n_b: [P] int32 real
-    counts, cos_edges: [NB]. -> [NB] int32 cumulative counts
-    ``#{valid (p,i,j): a[p,i] . b[p,j] >= cos_edges[k]}``."""
+    counts, cos_edges: [NB]. -> [P, NB] int32 cumulative counts of each
+    partition, ``#{valid (i,j): a[p,i] . b[p,j] >= cos_edges[k]}``. The
+    caller sums partitions (``ops.wide_sum``): a tier's total can pass int32
+    where no partition's count does."""
     P, M, d = a.shape
     N = b.shape[1]
     nbins = cos_edges.shape[0]
@@ -121,12 +123,12 @@ def pair_hist_masked_pallas(a, b, n_a, n_b, cos_edges, *, tm: int = TM,
                       interpret)
     out = call(jnp.asarray(n_a, jnp.int32), jnp.asarray(n_b, jnp.int32),
                jnp.asarray(cos_edges, jnp.float32), a, jnp.swapaxes(b, 1, 2))
-    return jnp.sum(out, axis=0, dtype=jnp.int32).reshape(-1)[:nbins]
+    return out.reshape(P, -1)[:, :nbins]
 
 
 def pair_count_masked_pallas(a, b, n_a, n_b, cos_min, *, tm: int = TM,
                              tn: int = TN, interpret: bool = False):
-    """Masked pair count: the one-edge histogram. -> scalar int32."""
+    """Masked pair count: the one-edge histogram. -> [P] int32."""
     edges = jnp.full((1,), cos_min, jnp.float32)
     return pair_hist_masked_pallas(a, b, n_a, n_b, edges, tm=tm, tn=tn,
-                                   interpret=interpret)[0]
+                                   interpret=interpret)[:, 0]

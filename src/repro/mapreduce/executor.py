@@ -31,9 +31,11 @@ two things keep memory and wall time bounded:
   ``overlap_hidden_s`` (hidden under compute), plus a per-split record
   stream for straggler analysis (``ft/stragglers.py``).
 
-``mesh=`` composes with streaming: each split (or the accumulated stream)
-reduces through the psum-sharded tier path, and the cross-split combine
-operates on the replicated partial.
+``mesh=`` composes with streaming: under a ``data`` axis each chip maps its
+own block of every split, each split (or the accumulated stream) crosses
+the chips in one all-to-all and reduces through the psum-sharded tier path,
+and the cross-split combine operates on the replicated partial. Spilled
+streams and lanes shuffle from one chip's stream.
 
 - **Concurrent lanes + fault tolerance** (``n_lanes=``, ``speculate=``,
   ``max_retries=``, ``deadline_s=``, ``chaos=``). A ``LanePool`` dispatches
@@ -73,7 +75,8 @@ from repro.mapreduce.codecs import get_codec
 from repro.mapreduce.instrumentation import StageStats
 from repro.mapreduce.job import (JobResult, MappedSplit,  # noqa: F401
                                  StreamSummary, concat_mapped,
-                                 host_shuffle_reduce, map_split_device,
+                                 concat_streams, host_shuffle_reduce,
+                                 map_split_device, map_stream,
                                  resolve_auto_job, shuffle_reduce_device,
                                  shuffle_reduce_device_streamed,
                                  validate_batch)
@@ -959,7 +962,11 @@ def _run_jobs_stream(jobs, source, *, mesh, device, codec, part, comb, K,
         m0, s0, r0 = stats.map_wall_s, stats.shuffle_wall_s, stats.reduce_wall_s
         if device:
             t0 = time.perf_counter()
-            m = map_split_device(part, codec, items_k, P)
+            # spill stores one stream per split; otherwise each chip of a
+            # data mesh maps its own block of the split
+            m = (map_split_device(part, codec, items_k, P)
+                 if spill_cfg is not None
+                 else map_stream(part, codec, items_k, P, mesh))
             stats.map_wall_s += time.perf_counter() - t0
             if comb is None:
                 if spill_cfg is not None:
@@ -1025,7 +1032,7 @@ def _run_jobs_stream(jobs, source, *, mesh, device, codec, part, comb, K,
                     totals, sd, sp, sr = spill_rt.finish(jobs, stats, mesh)
                 else:
                     totals, sd, sp, sr = shuffle_reduce_device(
-                        jobs, concat_mapped(mapped), P, stats, mesh)
+                        jobs, concat_streams(mapped), P, stats, mesh)
             else:
                 items_all = (host_items[0] if len(host_items) == 1
                              else np.concatenate(host_items, axis=0))

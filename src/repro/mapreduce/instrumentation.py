@@ -48,6 +48,11 @@ class StageStats:
     shuffle_wire_bytes: int = 0        # bytes that crossed the shuffle
     shuffle_raw_bytes: int = 0         # float32-equivalent (compression baseline)
     shuffle_index_impl: str = ""       # resolved index path: "jnp"|"host"|"numpy"
+    # cluster shuffle (a data mesh wider than one chip): owned rows and
+    # bucket entries the all-to-all moved off their home chip, and their
+    # wire bytes (padding of the send buffers not counted)
+    exchange_rows: int = 0
+    exchange_bytes: int = 0
     # reduce: per-partition kernels + combine
     reduce_wall_s: float = 0.0
     reduce_flops: float = 0.0
@@ -107,6 +112,7 @@ class StageStats:
     # partial StageStats when lanes merge their local stats into the shared one
     _ACCUM_FIELDS = ("n_items", "map_wall_s", "map_bytes", "shuffle_wall_s",
                      "shuffle_wire_bytes", "shuffle_raw_bytes",
+                     "exchange_rows", "exchange_bytes",
                      "reduce_wall_s", "reduce_flops", "reduce_bytes",
                      "fetch_wall_s", "combine_wall_s", "overlap_hidden_s",
                      "spill_bytes", "spill_wall_s", "spilled_splits",

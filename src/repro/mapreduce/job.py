@@ -20,10 +20,13 @@ composed into a ``MapReduceJob`` and executed by one of two engines:
   tier reduces through batched masked kernels (``pair_count_masked`` & co.:
   Pallas partition-grid kernels on TPU, the z-banded blocked engine
   elsewhere) instead of a sequential ``lax.map``. Under a ``data``-axis
-  mesh the tier arrays are padded so every tier's partition count divides
-  the axis size, each shard reduces its own rows, and tier partials
-  combine with a ``psum`` (``_reduce_tier_sharded``) — the fast path and
-  the scalable path are no longer mutually exclusive.
+  mesh the job runs as a cluster: each chip maps its own contiguous block
+  of the rows (``map_blocks``), one all-to-all moves every owned row and
+  border copy to the chip that owns its partition's tier slot
+  (``_exchange_jit``), tier partition counts are padded to a multiple of
+  the axis size so the tiers are born sharded, each shard reduces its own
+  rows, and tier partials combine with a ``psum``
+  (``_reduce_tier_sharded``).
 - ``engine="host"``: the original numpy shuffle + per-partition ``lax.map``
   reduce. Kept as the oracle-parity path (also under a mesh: the device
   engine's sharded results are bit-identical for exact codecs).
@@ -49,6 +52,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.compat import shard_map as _shard_map_compat
 from repro.mapreduce.codecs import ShuffleCodec, get_codec
@@ -71,6 +75,14 @@ def _data_axis_size(mesh) -> int:
     if mesh is None or "data" not in mesh.axis_names:
         return 1
     return int(mesh.shape["data"])
+
+
+def _data_devices(mesh) -> list:
+    """Entry ``c``: the devices that hold shard ``c`` of the ``data`` axis
+    (more than one where other mesh axes replicate it)."""
+    devs = np.moveaxis(np.asarray(mesh.devices),
+                       mesh.axis_names.index("data"), 0)
+    return [list(row) for row in devs.reshape(devs.shape[0], -1)]
 
 
 # ---------------------------------------------------------------------------
@@ -662,6 +674,156 @@ def _scatter_tiers_host(payloads, keys_h, dest_h, src_h, skey_h, o_starts,
     return tuple(out)
 
 
+# ---------------------------------------------------------------------------
+# Cluster shuffle: per-chip blocks, one all-to-all to the partition owners
+# ---------------------------------------------------------------------------
+#
+# Under a ``data`` axis of size D every entry of the mapped stream, an owned
+# row (id ``key``) or a bucket entry (id ``P + 1 + dest``), is sent once to
+# the chip that owns its partition's tier slot, and lands there at its final
+# row of that chip's slice of the tier. The host plans from per-chip counts
+# alone; the rows never leave the chips.
+
+_ROW = PartitionSpec("data")
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "n_parts"))
+def _count_entries(keys, dest_eff, *, mesh, n_parts):
+    """-> ``[D, 2 (P + 1)]`` int32: each chip's owned rows per key, then its
+    bucket entries per destination (index ``P`` counts padding)."""
+    def body(k, d):
+        return jnp.concatenate([jnp.bincount(k, length=n_parts + 1),
+                                jnp.bincount(d, length=n_parts + 1)]
+                               ).astype(jnp.int32)[None]
+
+    return _shard_map_compat(body, mesh=mesh, in_specs=(_ROW, _ROW),
+                             out_specs=_ROW, axis_names=frozenset({"data"}))(
+        keys, dest_eff)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "cap", "layout"))
+def _exchange_jit(payloads, keys, dest_eff, src, gid, send_base, dest_base,
+                  *, mesh, cap, layout):
+    """The cluster shuffle, one program: on each chip, sort its entries by
+    owner, pack them into ``D`` send buffers of ``cap`` rows, exchange the
+    buffers with one ``all_to_all``, and scatter what arrived into the
+    chip's slice of every tier.
+
+    ``gid`` ([2 (P + 1)], the same on every chip) orders the entry ids by
+    owning chip; ``send_base``/``dest_base`` ([D, 2 (P + 1)], one row per
+    chip, from ``_exchange_plan``) turn an entry's position in that order
+    into its send slot and its row in the owner's flat tier buffer.
+    ``layout``: per tier ``(q, C1, C2, owned_start, bucket_start)``, with
+    ``q`` partitions a chip. -> per tier (owned wire, bucket wire), each
+    ``[Pt, C, ...]`` sharded over ``data``."""
+    D = _data_axis_size(mesh)
+    n_parts = gid.shape[0] // 2 - 1
+    L = max((b0 + q * C2 for q, _, C2, _, b0 in layout), default=0)
+
+    def body(payloads, keys, dest_eff, src, gid, send_base, dest_base):
+        ids = jnp.concatenate([keys, dest_eff + (n_parts + 1)])
+        rows = jnp.concatenate([jnp.arange(keys.shape[0], dtype=jnp.int32),
+                                src])
+        g = gid[ids]
+        order = jnp.argsort(g)
+        gs = g[order]
+        i = jnp.arange(ids.shape[0], dtype=jnp.int32)
+        slot = send_base[0, gs] + i          # >= D * cap: not sent
+        take = rows[order]
+        send = [jnp.zeros((D * cap,) + p.shape[1:], p.dtype)
+                .at[slot].set(p[take], mode="drop", unique_indices=True)
+                for p in payloads]
+        # unused slots point past the tier buffer, each at its own row
+        unused = (L + jax.lax.axis_index("data") * (D * cap)
+                  + jnp.arange(D * cap, dtype=jnp.int32))
+        send.append(unused.at[slot].set(dest_base[0, gs] + i, mode="drop",
+                                        unique_indices=True))
+        recv = [jax.lax.all_to_all(x, "data", 0, 0, tiled=True)
+                for x in send]
+        pos = recv.pop()
+        flat = [jnp.zeros((L,) + r.shape[1:], r.dtype)
+                .at[pos].set(r, mode="drop", unique_indices=True)
+                for r in recv]
+
+        def cut(start, q, C):
+            return tuple(f[start:start + q * C].reshape((q, C) + f.shape[1:])
+                         for f in flat)
+
+        return tuple((cut(o0, q, C1), cut(b0, q, C2))
+                     for q, C1, C2, o0, b0 in layout)
+
+    return _shard_map_compat(
+        body, mesh=mesh,
+        in_specs=(_ROW, _ROW, _ROW, _ROW, PartitionSpec(), _ROW, _ROW),
+        out_specs=_ROW, axis_names=frozenset({"data"}))(
+        payloads, keys, dest_eff, src, gid, send_base, dest_base)
+
+
+def _exchange_plan(counts, plan, specs, D: int):
+    """Host side of the exchange, from ``counts`` (``_count_entries``) and
+    the tier plan. A tier's partition slot ``l`` lives on chip ``l // q``;
+    entries of one id keep chip order (chip 0's first).
+
+    -> (gid, send_base, dest_base, cap, layout, rows_crossing): the
+    tables ``_exchange_jit`` takes, the send buffer's capacity (rounded up
+    so a catalog that moves slightly does not compile anew), the tier
+    layout of each chip, and the entries whose owner is not their chip."""
+    counts = np.asarray(counts, np.int64)
+    G = counts.shape[1]
+    n_parts = G // 2 - 1
+    owner = np.full(G, D, np.int64)              # D: padding, never sent
+    base = np.zeros(G, np.int64)
+    layout, off = [], 0
+    for (ids, C1, C2), (Pt, _, _) in zip(plan, specs):
+        q = Pt // D
+        local = np.arange(len(ids))
+        o0, b0 = off, off + q * C1
+        owner[ids] = owner[n_parts + 1 + ids] = local // q
+        base[ids] = o0 + (local % q) * C1
+        base[n_parts + 1 + ids] = b0 + (local % q) * C2
+        layout.append((q, C1, C2, o0, b0))
+        off = b0 + q * C2
+    order = np.argsort(owner, kind="stable")
+    gid = np.empty(G, np.int32)
+    gid[order] = np.arange(G, dtype=np.int32)
+    og = owner[order]
+    cg = counts[:, order]                        # [D, G] in gid order
+    start = np.cumsum(cg, axis=1) - cg           # first sorted position
+    per_owner = np.stack([np.bincount(og, weights=c, minlength=D + 1)
+                          for c in cg]).astype(np.int64)
+    owner_start = np.cumsum(per_owner, axis=1) - per_owner
+    widest = max(int(per_owner[:, :D].max()), 1)
+    cap = _round_up(widest, max(8, 1 << max(widest.bit_length() - 6, 0)))
+    send_base = np.where(og < D, og * cap - np.take_along_axis(
+        owner_start, np.broadcast_to(og, cg.shape), axis=1), D * cap)
+    before = np.cumsum(counts, axis=0) - counts  # rows of lower chips
+    dest_base = base[order] + before[:, order] - start
+    crossing = int(counts[(owner < D) & (owner != np.arange(D)[:, None])]
+                   .sum())
+    return (gid, send_base.astype(np.int32), dest_base.astype(np.int32), cap,
+            tuple(layout), crossing)
+
+
+def _sharded_rows(parts, mesh, length: int, fill, like):
+    """One ``[D * length, ...]`` array sharded over ``data`` from per-chip
+    pieces (piece ``c``, padded with ``fill`` to ``length`` rows, on the
+    chips of shard ``c``; ``None`` is all padding). Rows stay on their
+    chips."""
+    devs = _data_devices(mesh)
+    shards = []
+    for x, devices in zip(parts, devs):
+        if x is None:
+            x = jnp.full((length,) + like.shape[1:], fill, like.dtype,
+                         device=devices[0])
+        elif x.shape[0] < length:
+            x = jnp.pad(x, [(0, length - x.shape[0])] + [(0, 0)] * (x.ndim - 1),
+                        constant_values=fill)
+        shards += [jax.device_put(x, d) for d in devices]
+    return jax.make_array_from_single_device_arrays(
+        (len(devs) * length,) + like.shape[1:], NamedSharding(mesh, _ROW),
+        shards)
+
+
 def _make_sharded_body(reducers, codec, mesh):
     """shard_map'd decode + masked reduce + psum for traceable reducers."""
     from jax.sharding import PartitionSpec as P
@@ -707,13 +869,13 @@ def _reduce_tier_sharded(reducers, codec, tier: TierData, mesh):
     """Reduce one tier across the mesh's ``data`` axis and psum-combine.
 
     Tier rows are contiguous per shard (shard ``s`` owns rows
-    ``[s*Pt/D, (s+1)*Pt/D)``; phantom partitions mask to nothing). Two
-    sub-paths mirror the ``ops.py`` backend split:
+    ``[s*Pt/D, (s+1)*Pt/D)``; phantom partitions mask to nothing), and the
+    cluster shuffle already left them there. Two sub-paths mirror the
+    ``ops.py`` backend split:
 
     - every reducer traceable (Pallas masked kernels on TPU, pure-jnp
       reducers anywhere): decode + masked reduce + ``lax.psum`` run INSIDE
-      one ``shard_map`` region, so the wire payload is resharded once and
-      each shard's kernels run on its own device.
+      one ``shard_map`` region, each shard's kernels on its own device.
     - otherwise (the z-banded blocked engine plans its blocks on the host,
       which cannot happen under tracing): each shard's rows are sliced and
       reduced eagerly, then the stacked per-shard partials cross ONE
@@ -790,6 +952,42 @@ def map_split_device(partitioner: Partitioner, codec: ShuffleCodec, items,
         return MappedSplit(payloads, keys, dest_eff, src, skey,
                            n_rows=int(items.shape[0]), d=int(items.shape[1]),
                            nbytes_in=int(items.nbytes))
+
+
+def map_blocks(partitioner: Partitioner, codec: ShuffleCodec, items, P: int,
+               mesh) -> list:
+    """Map stage of a cluster: the rows cut into one contiguous block per
+    shard of the mesh's ``data`` axis (a node's HDFS block), each put on
+    its chip and mapped there. -> one ``MappedSplit`` per shard, ``None``
+    for an empty block after the first."""
+    rows = items if isinstance(items, jax.Array) else np.asarray(items)
+    devs = _data_devices(mesh)
+    step = -(-rows.shape[0] // len(devs))
+    out = []
+    for c, devices in enumerate(devs):
+        block = rows[c * step:(c + 1) * step]
+        out.append(map_split_device(partitioner, codec,
+                                    jax.device_put(block, devices[0]), P)
+                   if c == 0 or block.shape[0] else None)
+    return out
+
+
+def map_stream(partitioner: Partitioner, codec: ShuffleCodec, items, P: int,
+               mesh=None):
+    """The map stage for ``mesh``: one ``MappedSplit``, or under a
+    ``data`` axis wider than one the per-chip ``map_blocks``."""
+    if _data_axis_size(mesh) > 1:
+        return map_blocks(partitioner, codec, items, P, mesh)
+    return map_split_device(partitioner, codec, items, P)
+
+
+def concat_streams(streams: list):
+    """``concat_mapped`` of map outputs, chip by chip where they are
+    ``map_blocks`` lists."""
+    if not isinstance(streams[0], list):
+        return concat_mapped(streams)
+    per_chip = [[b for b in bs if b is not None] for bs in zip(*streams)]
+    return [concat_mapped(bs) if bs else None for bs in per_chip]
 
 
 def concat_mapped(splits: "list[MappedSplit]") -> MappedSplit:
@@ -936,15 +1134,19 @@ class ResidentCatalog:
 
 
 def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
-                    pad_value: float, m: MappedSplit, P: int,
+                    pad_value: float, m, P: int,
                     stats: StageStats, mesh=None,
                     cost_basis: str = "pairs") -> ResidentCatalog:
     """Shuffle one mapped stream into device-resident tiers: count, tier,
     argsort-bucket, scatter in wire dtype — the shuffle half of
-    ``shuffle_reduce_device``, accumulating (``+=``) into ``stats``. Tier
-    partition counts are padded to a multiple of the mesh's data axis size
-    with phantom (zero-count) partitions, so every tier splits evenly
-    across shards.
+    ``shuffle_reduce_device``, accumulating (``+=``) into ``stats``.
+
+    Under a ``data`` axis of size D > 1 ``m`` is a ``map_blocks`` list (a
+    single ``MappedSplit`` counts as chip 0's block, the others empty): the
+    host plans from per-chip counts, and ``_exchange_jit`` moves each entry
+    to its owner, so the tiers are born sharded over ``data``. Tier
+    partition counts are padded to a multiple of D with phantom
+    (zero-count) partitions, so every tier splits evenly across shards.
 
     ``tile="auto"`` asks the cost model for the tile quantum AND the tier
     split minimizing the predicted reduce wall (instead of padded-cell
@@ -954,22 +1156,38 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
     -> ResidentCatalog."""
     from repro.core.cost_model import StageCost, get_cost_model
     D = _data_axis_size(mesh)
+    if D > 1:
+        blocks = list(m) if isinstance(m, list) else [m]
+        blocks += [None] * (D - len(blocks))
+        m = next(b for b in blocks if b is not None)
+        n_rows = sum(b.n_rows for b in blocks if b is not None)
+    else:
+        n_rows = m.n_rows
     d = m.d
     tr = get_tracer()
     with tr.span("shuffle", cat="stage", engine="device"):
         t0 = time.perf_counter()
         with tr.span("shuffle.wait", cat="stage"):
-            keys_h = np.asarray(jax.block_until_ready(m.keys))
-            dest_h = np.asarray(m.dest_eff)
+            if D > 1:
+                stream = _blocked_stream(blocks, mesh, P)
+                counts = np.asarray(_count_entries(
+                    stream.keys, stream.dest_eff, mesh=mesh, n_parts=P))
+            else:
+                keys_h = np.asarray(jax.block_until_ready(m.keys))
+                dest_h = np.asarray(m.dest_eff)
         with tr.span("shuffle.plan", cat="stage"):
             # keys == P marks payload-only rows (carried for the bucket
             # entries that reference them — spilled range reads use this for
             # cross-range border rows); like dest == P they are excluded
             # from owned counts/scatter.
-            n_owned = np.bincount(keys_h, minlength=P + 1)[:P].astype(
-                np.int64)
-            n_bucket = np.bincount(dest_h, minlength=P + 1)[:P].astype(
-                np.int64)
+            if D > 1:
+                n_owned = counts[:, :P].sum(axis=0, dtype=np.int64)
+                n_bucket = counts[:, P + 1:-1].sum(axis=0, dtype=np.int64)
+            else:
+                n_owned = np.bincount(keys_h, minlength=P + 1)[:P].astype(
+                    np.int64)
+                n_bucket = np.bincount(dest_h, minlength=P + 1)[:P].astype(
+                    np.int64)
             tile_req = tile
             if tile == "auto":
                 tile, plan, _ = get_cost_model().plan_shuffle(
@@ -978,50 +1196,67 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
             else:
                 plan = plan_tiers(n_owned, n_bucket, tile,
                                   pad_partitions_to=D)
-            part_tier = np.full(P + 1, -1, np.int32)
-            part_local = np.zeros(P + 1, np.int32)
-            specs = []
-            for t, (ids, C1, C2) in enumerate(plan):
-                part_tier[ids] = t
-                part_local[ids] = np.arange(len(ids), dtype=np.int32)
-                specs.append((_round_up(len(ids), D), C1, C2))
-            o_starts = np.zeros(P + 1, np.int32)
-            np.cumsum(n_owned, out=o_starts[1:])
-            b_starts = np.zeros(P + 1, np.int32)
-            np.cumsum(n_bucket, out=b_starts[1:])
-        stats.shuffle_index_impl = "jnp" if _use_jnp_indices() else "host"
-        with tr.span("shuffle.scatter", cat="stage"):
-            if _use_jnp_indices():
-                scattered = _scatter_tiers_jit(
-                    m.payloads, m.keys, m.dest_eff, m.src,
-                    jnp.asarray(o_starts), jnp.asarray(b_starts),
-                    jnp.asarray(part_tier), jnp.asarray(part_local),
-                    specs=tuple(specs))
+            specs = tuple((_round_up(len(ids), D), C1, C2)
+                          for ids, C1, C2 in plan)
+            if D > 1:
+                gid, send_base, dest_base, cap, layout, crossing = \
+                    _exchange_plan(counts, plan, specs, D)
             else:
-                src_h = np.asarray(m.src)
-                live = dest_h < P     # drop non-replicated border slots
-                if not live.all():    # before sorting: fewer copies = less
-                    dest_h, src_h = dest_h[live], src_h[live]   # sort work
-                scattered = _scatter_tiers_host(
-                    m.payloads, keys_h, dest_h, src_h,
-                    None if m.skey is None else np.asarray(m.skey),
-                    o_starts, b_starts, part_tier, part_local, tuple(specs))
-            scattered = jax.block_until_ready(scattered)
+                part_tier = np.full(P + 1, -1, np.int32)
+                part_local = np.zeros(P + 1, np.int32)
+                for t, (ids, _, _) in enumerate(plan):
+                    part_tier[ids] = t
+                    part_local[ids] = np.arange(len(ids), dtype=np.int32)
+                o_starts = np.zeros(P + 1, np.int32)
+                np.cumsum(n_owned, out=o_starts[1:])
+                b_starts = np.zeros(P + 1, np.int32)
+                np.cumsum(n_bucket, out=b_starts[1:])
+        stats.shuffle_index_impl = ("jnp" if D > 1 or _use_jnp_indices()
+                                    else "host")
+        if D > 1:
+            row_bytes = codec.device_bytes_per_item(d)
+            with tr.span("shuffle.exchange", cat="stage",
+                         counters={"exchange_bytes": crossing * row_bytes,
+                                   "exchange_rows": crossing}):
+                scattered = jax.block_until_ready(_exchange_jit(
+                    stream.payloads, stream.keys, stream.dest_eff, stream.src,
+                    gid, send_base, dest_base, mesh=mesh, cap=cap,
+                    layout=layout))
+            stats.exchange_rows += crossing
+            stats.exchange_bytes += crossing * row_bytes
+        else:
+            with tr.span("shuffle.scatter", cat="stage"):
+                if _use_jnp_indices():
+                    scattered = _scatter_tiers_jit(
+                        m.payloads, m.keys, m.dest_eff, m.src,
+                        jnp.asarray(o_starts), jnp.asarray(b_starts),
+                        jnp.asarray(part_tier), jnp.asarray(part_local),
+                        specs=specs)
+                else:
+                    src_h = np.asarray(m.src)
+                    live = dest_h < P     # drop non-replicated border slots
+                    if not live.all():    # before sorting: fewer copies =
+                        dest_h, src_h = dest_h[live], src_h[live]   # less sort
+                    scattered = _scatter_tiers_host(
+                        m.payloads, keys_h, dest_h, src_h,
+                        None if m.skey is None else np.asarray(m.skey),
+                        o_starts, b_starts, part_tier, part_local, specs)
+                scattered = jax.block_until_ready(scattered)
         with tr.span("shuffle.tiers", cat="stage"):
             tiers = []
             shard_pad = np.zeros(D, np.float64)
             shard_real = np.zeros(D, np.float64)
             for ((ids, C1, C2), (Pt, _, _), (own, bkt)) in zip(
                     plan, specs, scattered):
-                no_t = np.zeros(Pt, np.int64)
-                nb_t = np.zeros(Pt, np.int64)
+                no_t = np.zeros(Pt, np.int32)
+                nb_t = np.zeros(Pt, np.int32)
                 no_t[:len(ids)] = n_owned[ids]
                 nb_t[:len(ids)] = n_bucket[ids]
-                tiers.append(TierData(ids, own, bkt,
-                                      jnp.asarray(no_t, jnp.int32),
-                                      jnp.asarray(nb_t, jnp.int32), C1=C1,
-                                      C2=C2, Pt=Pt))
-                shard_real += (no_t * nb_t).reshape(D, Pt // D).sum(axis=1)
+                tiers.append(TierData(ids, own, bkt, _put_counts(no_t, mesh),
+                                      _put_counts(nb_t, mesh), C1=C1, C2=C2,
+                                      Pt=Pt))
+                shard_real += (no_t.astype(np.int64) * nb_t).reshape(
+                    D, Pt // D).sum(axis=1)
                 shard_pad += float(Pt // D) * C1 * C2
             sd = DeviceShuffledData(tiers, n_owned, n_bucket)
         n_shuffled = int(n_bucket.sum())
@@ -1035,15 +1270,46 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
     stats.predicted_shuffle_wall_s += get_cost_model().predict_wall(
         StageCost(flops=0.0, hbm_bytes=3.0 * wire + 16.0 * n_shuffled,
                   n_dispatch=len(plan) + 2))
-    stats.n_items += m.n_rows
+    stats.n_items += n_rows
     stats.n_partitions = P
     stats.codec = codec.name
     stats.engine = "device"
     stats.n_shards = D
     return ResidentCatalog(partitioner, codec, tile_req, pad_value, sd, P,
                            mesh=mesh, shard_pad=shard_pad,
-                           shard_real=shard_real, n_rows=m.n_rows, d=d,
+                           shard_real=shard_real, n_rows=n_rows, d=d,
                            tile_resolved=int(tile))
+
+
+def _blocked_stream(blocks, mesh, P: int) -> MappedSplit:
+    """Per-chip map outputs as one stream of ``[D * length, ...]`` arrays
+    sharded over ``data``, each chip's rows and entries padded to a common
+    length (padding rows and entries carry id ``P``: nobody's); ``src``
+    stays chip-local."""
+    m = next(b for b in blocks if b is not None)
+    n = max(max((b.n_rows for b in blocks if b is not None), default=0), 1)
+    e = max(max((b.dest_eff.shape[0] for b in blocks if b is not None),
+                default=0), 1)
+
+    def rows(get, length, fill):
+        return _sharded_rows([None if b is None else get(b) for b in blocks],
+                             mesh, length, fill, get(m))
+
+    return MappedSplit(
+        payloads=tuple(rows(lambda b, k=k: b.payloads[k], n, 0)
+                       for k in range(len(m.payloads))),
+        keys=rows(lambda b: b.keys, n, P),
+        dest_eff=rows(lambda b: b.dest_eff, e, P),
+        src=rows(lambda b: b.src, e, 0),
+        skey=None, n_rows=sum(b.n_rows for b in blocks if b is not None),
+        d=m.d)
+
+
+def _put_counts(x: np.ndarray, mesh):
+    """A tier's per-partition counts, sharded like its rows."""
+    if _data_axis_size(mesh) > 1:
+        return jax.device_put(x, NamedSharding(mesh, _ROW))
+    return jnp.asarray(x)
 
 
 def shuffle_once(partitioner: Partitioner, items, *, codec="identity",
@@ -1066,9 +1332,10 @@ def shuffle_once(partitioner: Partitioner, items, *, codec="identity",
     meter = get_meter()
     mtok = meter.begin()
     t0 = time.perf_counter()
-    m = map_split_device(partitioner, codec, items, P)
+    m = map_stream(partitioner, codec, items, P, mesh)
     stats.map_wall_s += time.perf_counter() - t0
-    stats.map_bytes += m.nbytes_in
+    stats.map_bytes += sum(b.nbytes_in for b in
+                           (m if isinstance(m, list) else [m]) if b)
     cat = _shuffle_mapped(partitioner, codec, tile, pad_value, m, P, stats,
                           mesh)
     meter.attribute(mtok, stats)

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.sky import ARCSEC
-from repro.kernels.zones_pairs.ops import pair_hist, pair_hist_masked
+from repro.kernels.zones_pairs.ops import (pair_hist, pair_hist_masked,
+                                           wide_sum, wide_value)
 from repro.mapreduce.job import MapReduceJob, Reducer, ShuffledData, run_job
 from repro.mapreduce.zones import ZonePartitioner
 
@@ -27,7 +28,8 @@ DEFAULT_EDGES_ARCSEC = tuple(float(e) for e in range(1, 61))
 
 @dataclasses.dataclass(frozen=True)
 class PairHistReducer(Reducer):
-    """Cumulative per-edge pair counts per zone; finalize differentiates."""
+    """Cumulative per-edge pair counts per zone, as ``wide_sum`` digits;
+    finalize differentiates."""
 
     edges_rad: tuple
     use_pallas: bool | None = None
@@ -36,7 +38,7 @@ class PairHistReducer(Reducer):
         return jnp.asarray(np.cos(np.asarray(self.edges_rad)), jnp.float32)
 
     def per_partition(self, owned_p, bucket_p):
-        return pair_hist(owned_p, bucket_p, self._cos_edges())
+        return wide_sum(pair_hist(owned_p, bucket_p, self._cos_edges())[None])
 
     def reduce_partitions(self, owned, bucket, n_owned, n_bucket):
         return pair_hist_masked(owned, bucket, n_owned, n_bucket,
@@ -48,7 +50,7 @@ class PairHistReducer(Reducer):
         return masked_uses_pallas(self.use_pallas)
 
     def finalize(self, total, sd: ShuffledData):
-        cum = np.asarray(total).astype(np.int64)
+        cum = wide_value(total)
         cum -= int(sd.n_owned.sum())   # self pairs (theta=0) hit every edge
         cum //= 2                      # each unordered pair seen twice
         return np.diff(np.concatenate([[0], cum]))

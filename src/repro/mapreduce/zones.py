@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data import sky
-from repro.kernels.zones_pairs.ops import pair_count, pair_count_masked
+from repro.kernels.zones_pairs.ops import (pair_count, pair_count_masked,
+                                           wide_sum, wide_value)
 from repro.mapreduce.job import (MapReduceJob, Partitioner, Reducer,
                                  ShuffledData, run_job)
 
@@ -107,14 +108,15 @@ class ZonePartitioner(Partitioner):
 
 @dataclasses.dataclass(frozen=True)
 class PairCountReducer(Reducer):
-    """Blockwise within-radius pair count per zone; finalize removes self
-    pairs and the double-count."""
+    """Blockwise within-radius pair count per zone, as ``wide_sum`` digits;
+    finalize removes self pairs and the double-count."""
 
     radius: float
     use_pallas: bool | None = None
 
     def per_partition(self, owned_p, bucket_p):
-        return pair_count(owned_p, bucket_p, float(np.cos(self.radius)))
+        return wide_sum(pair_count(owned_p, bucket_p,
+                                   float(np.cos(self.radius)))[None])
 
     def reduce_partitions(self, owned, bucket, n_owned, n_bucket):
         return pair_count_masked(owned, bucket, n_owned, n_bucket,
@@ -126,7 +128,7 @@ class PairCountReducer(Reducer):
         return masked_uses_pallas(self.use_pallas)
 
     def finalize(self, total, sd: ShuffledData):
-        return (int(total) - int(sd.n_owned.sum())) // 2
+        return (int(wide_value(total)) - int(sd.n_owned.sum())) // 2
 
     def flops(self, sd: ShuffledData):
         # per zone: C1*C2 dot products (2*3 FLOPs) + compares

@@ -29,7 +29,10 @@ carries them on exit: ``jax_traces``, ``jax_lowerings``, ``jax_cache_hits``
 not serve) and ``jax_compile_s`` (the three durations summed, cache reads
 included). They go to the profiler event's stats always, and to the Chrome
 event's ``args`` where any is non-zero. The listener is registered the
-first time such a span opens, and never in a run without one.
+first time such a span opens, and never in a run without one. A span may
+also carry counters of its own, known when it opens (``span(name,
+counters={...})``: the cluster shuffle's ``exchange_bytes`` and
+``exchange_rows``); they go to the same two places, always.
 
 The module-level current tracer defaults to ``NullTracer`` whose
 ``span()`` / ``ids()`` return a shared reentrant no-op context manager,
@@ -133,10 +136,11 @@ class _ProfiledSpan:
     """What every span shares: the compile counters, and under a profiler
     session a ``mr:<name>`` annotation that carries them as its stats."""
 
-    __slots__ = ("name", "counts", "_ann")
+    __slots__ = ("name", "extra", "counts", "_ann")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, counters=None):
         self.name = name
+        self.extra = counters or {}
 
     def __enter__(self) -> Dict[str, float]:
         self.counts = _COUNTS.open()
@@ -149,7 +153,7 @@ class _ProfiledSpan:
     def __exit__(self, *exc):
         _COUNTS.close(self.counts)
         if self._ann is not None:
-            self._ann.set_metadata(**self.counts)
+            self._ann.set_metadata(**self.counts, **self.extra)
             self._ann.__exit__(*exc)
         return False
 
@@ -175,10 +179,10 @@ class NullTracer:
 
     enabled = False
 
-    def span(self, name: str, cat: str = "stage", **ids):
+    def span(self, name: str, cat: str = "stage", counters=None, **ids):
         if not _profiling():
             return _NULL_CTX
-        return _ProfiledSpan(name)
+        return _ProfiledSpan(name, counters)
 
     def ids(self, **ids) -> _NullCtx:
         return _NULL_CTX
@@ -261,9 +265,11 @@ class Tracer:
         return ev
 
     @contextlib.contextmanager
-    def span(self, name: str, cat: str = "stage", **ids) -> Iterator[None]:
-        """Record a complete span around the with-body (closes in finally)."""
-        prof = _ProfiledSpan(name)
+    def span(self, name: str, cat: str = "stage", counters=None,
+             **ids) -> Iterator[None]:
+        """Record a complete span around the with-body (closes in finally);
+        ``counters`` go to its args."""
+        prof = _ProfiledSpan(name, counters)
         counts = prof.__enter__()
         t0 = self._clock()
         with self._lock:
@@ -276,6 +282,7 @@ class Tracer:
             ev = self._event(name, cat, "X", t0, t1 - t0, ids)
             if any(counts.values()):
                 ev["args"].update(counts)
+            ev["args"].update(prof.extra)
             with self._lock:
                 self._closed += 1
                 self.events.append(ev)

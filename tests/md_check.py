@@ -1,4 +1,5 @@
-"""Multi-device semantics checks (run as a subprocess with 8 host devices).
+"""Multi-device semantics checks (run as a subprocess with 8 host devices;
+``mapreduce-cluster`` with 4).
 
 Usage: python tests/md_check.py <check-name>
 Checks exit 0 on success; any assertion failure is a non-zero exit.
@@ -6,7 +7,10 @@ Checks exit 0 on success; any assertion failure is a non-zero exit.
 import os
 import sys
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+N_DEVICES = {"mapreduce-cluster": 4}.get(sys.argv[1:2][0] if sys.argv[1:]
+                                         else "", 8)
+os.environ["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
+                           f"{N_DEVICES}")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -180,22 +184,31 @@ def check_mapreduce_device_sharded():
     np.testing.assert_array_equal(hd, hh)
     np.testing.assert_array_equal(hd, np.bincount(toks, minlength=500))
 
-    # both shuffle index impls produce identical results AND metadata
+    # both shuffle index impls produce identical results AND metadata; on
+    # a data mesh the cluster exchange computes the index metadata on the
+    # chips whichever impl is asked for
     xyz = sky.make_catalog(700, 3)
     j = neighbor_search_job(0.09, codec="int16", tile=64)
     want = run_job(j, xyz, mesh=mesh, engine="device")
+    one_host = run_job(j, xyz, engine="device")
     old = job_mod.SHUFFLE_INDEX_IMPL
     job_mod.SHUFFLE_INDEX_IMPL = "jnp"
     try:
         got = run_job(j, xyz, mesh=mesh, engine="device")
+        one_jnp = run_job(j, xyz, engine="device")
     finally:
         job_mod.SHUFFLE_INDEX_IMPL = old
-    assert got.output == want.output
-    assert want.stats.shuffle_index_impl == "host"      # CPU backend default
+    assert got.output == want.output == one_host.output == one_jnp.output
+    assert one_host.stats.shuffle_index_impl == "host"  # CPU backend default
+    assert one_jnp.stats.shuffle_index_impl == "jnp"
+    assert want.stats.shuffle_index_impl == "jnp"
     assert got.stats.shuffle_index_impl == "jnp"
     for f in ("shuffle_wire_bytes", "n_partitions", "reduce_padded_ratio",
               "shard_padded_ratio", "reduce_bytes"):
         assert getattr(got.stats, f) == getattr(want.stats, f), f
+    for f in ("shuffle_wire_bytes", "n_partitions", "reduce_padded_ratio",
+              "reduce_bytes"):
+        assert getattr(one_jnp.stats, f) == getattr(one_host.stats, f), f
 
     # traceable in-shard_map path: pair kernels through Pallas interpret,
     # single job AND batched (two reducers fused in one shard_map region)
@@ -439,6 +452,125 @@ def check_mapreduce_lanes_sharded():
     print("mapreduce lanes across 8 devices == monolithic OK")
 
 
+def check_mapreduce_cluster():
+    """The cluster shuffle on a four-chip ``data`` mesh (4 host devices):
+    each chip maps its own block of rows and one all-to-all moves every
+    entry to its partition's owner. On a seeded clustered catalog over 32
+    zones, with a row count the chips do not divide:
+
+    (a) ``run_jobs`` on the mesh equals the one-device device path bit for
+        bit (identity and int16) and the host engine (identity);
+    (b) each chip's slice of every resident tier holds exactly the rows the
+        one-device layout holds for the partitions it owns (rows within a
+        partition in any order), padding and phantom partitions zero;
+    (c) the ``shuffle.exchange`` span's byte counter equals the owned rows
+        and bucket entries whose owner is not their block's chip, times the
+        wire bytes of a row.
+    """
+    import jax.numpy as jnp
+    from repro.data import sky
+    from repro.mapreduce import (ZonePartitioner, neighbor_search_job,
+                                 neighbor_statistics_job, run_jobs,
+                                 shuffle_once)
+    from repro.mapreduce.codecs import get_codec
+    from repro.obs.trace import Tracer, use_tracer
+
+    D = 4
+    mesh = make_mesh((D,), ("data",))
+    rng = np.random.default_rng(15)
+    xyz = sky.make_catalog(2403, 15)
+    centres = xyz[rng.integers(0, len(xyz), 12)]
+    clumps = np.repeat(centres, 50, axis=0) + rng.normal(0, 0.01, (600, 3))
+    xyz = np.concatenate([xyz, clumps])
+    xyz = (xyz / np.linalg.norm(xyz, axis=1, keepdims=True)).astype(
+        np.float32)[rng.permutation(len(xyz))]
+    radius, tile = 0.1, 64
+    part = ZonePartitioner(radius)
+    assert part.n_partitions(xyz) >= 8
+    edges = np.linspace(0.02, radius, 5)
+
+    # (a)
+    for codec in ("identity", "int16"):
+        jobs = [neighbor_search_job(radius, partitioner=part, tile=tile,
+                                    codec=codec),
+                neighbor_statistics_job(edges / sky.ARCSEC, partitioner=part,
+                                        tile=tile, codec=codec)]
+        r4 = run_jobs(jobs, xyz, mesh=mesh, engine="device")
+        r1 = run_jobs(jobs, xyz, engine="device")
+        assert r4[0].output == r1[0].output, (codec, r4[0].output,
+                                              r1[0].output)
+        np.testing.assert_array_equal(r4[1].output, r1[1].output)
+        assert r4[0].stats.n_shards == D and r4[0].stats.exchange_rows > 0
+        if codec == "identity":
+            rh = run_jobs(jobs, xyz, engine="host")
+            assert r4[0].output == rh[0].output == sky.brute_force_pairs(
+                xyz, radius)
+            np.testing.assert_array_equal(r4[1].output, rh[1].output)
+
+    # (b), (c)
+    codec = "identity"
+    tracer = Tracer()
+    with use_tracer(tracer):
+        cat = shuffle_once(part, xyz, codec=codec, tile=tile, mesh=mesh)
+    one = shuffle_once(part, xyz, codec=codec, tile=tile)
+    where = {}
+    for t in one.sd.tiers:
+        for slot, p in enumerate(t.part_ids):
+            where[int(p)] = (t, slot)
+
+    def canon(rows):
+        return rows[np.lexsort(rows.T[::-1])]
+
+    owner = np.full(cat.P + 1, D)
+    for t in cat.sd.tiers:
+        q = t.Pt // D
+        owner[t.part_ids] = np.arange(len(t.part_ids)) // q
+        for name, wire, C, counts in (
+                ("owned", t.owned_wire[0], t.C1, cat.sd.n_owned),
+                ("bucket", t.bucket_wire[0], t.C2, cat.sd.n_bucket)):
+            chips = set()
+            for shard in wire.addressable_shards:
+                chips.add(shard.device)
+                lo = shard.index[0].start or 0
+                assert shard.data.shape == (q, C, 3), shard.data.shape
+                local = np.asarray(shard.data)
+                for j in range(q):
+                    slot = lo + j
+                    if slot >= len(t.part_ids):     # phantom partition
+                        assert not local[j].any(), (name, slot)
+                        continue
+                    p = int(t.part_ids[slot])
+                    n = int(counts[p])
+                    t1, s1 = where[p]
+                    ref = np.asarray((t1.owned_wire if name == "owned"
+                                      else t1.bucket_wire)[0][s1])
+                    np.testing.assert_array_equal(canon(local[j, :n]),
+                                                  canon(ref[:n]))
+                    assert not local[j, n:].any(), (name, p)
+            assert len(chips) == D, (name, chips)
+
+    # independent count of the entries that leave their home chip
+    keys = np.asarray(part.assign_device(jnp.asarray(xyz)))
+    dest, src, valid = (np.asarray(a) for a in part.bucket_entries_device(
+        jnp.asarray(xyz), jnp.asarray(keys), cat.P))
+    step = -(-len(xyz) // D)
+    home = np.arange(len(xyz)) // step
+    crossing = (int(np.sum(owner[keys] != home))
+                + int(np.sum(valid & (owner[np.where(valid, dest, cat.P)]
+                                      != home[src]))))
+    row_bytes = get_codec(codec).device_bytes_per_item(3)
+    spans = [e for e in tracer.events if e["name"] == "shuffle.exchange"]
+    assert len(spans) == 1, [e["name"] for e in tracer.events]
+    args = spans[0]["args"]
+    assert args["exchange_rows"] == crossing, (args, crossing)
+    assert args["exchange_bytes"] == crossing * row_bytes, (args, crossing)
+    assert cat.load_stats.exchange_bytes == crossing * row_bytes
+    n_entries = len(xyz) + int(valid.sum())
+    assert 0.6 < crossing / n_entries < 0.9, crossing / n_entries
+    print(f"mapreduce cluster shuffle on 4 chips OK ({crossing} of "
+          f"{n_entries} entries crossed)")
+
+
 if __name__ == "__main__":
     checks = {
         "hier": check_hierarchical_psum,
@@ -451,5 +583,6 @@ if __name__ == "__main__":
         "mapreduce-streaming": check_mapreduce_streaming_sharded,
         "mapreduce-service": check_mapreduce_service_sharded,
         "mapreduce-lanes": check_mapreduce_lanes_sharded,
+        "mapreduce-cluster": check_mapreduce_cluster,
     }
     checks[sys.argv[1]]()

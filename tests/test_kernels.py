@@ -1,5 +1,7 @@
 """Pallas kernel sweeps: interpret-mode kernel vs pure-jnp oracle over
 shapes x dtypes (per assignment: every kernel gets an allclose sweep)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -63,7 +65,7 @@ def test_pair_count_sweep(m, n, tm, tn, radius):
     got = pair_count_masked_pallas(a, b, na, nb, cm, tm=tm, tn=tn,
                                    interpret=True)
     want = pair_count_ref(a[0], b[0], cm)
-    assert int(got) == int(want)
+    assert got.shape == (1,) and int(got[0]) == int(want)
 
 
 def test_pair_count_exclude_self():
@@ -74,7 +76,7 @@ def test_pair_count_exclude_self():
     got = pair_count_masked_pallas(a, a, na, na, cm, tm=128, tn=128,
                                    interpret=True)
     want = pair_count_ref(a[0], a[0], cm, exclude_self=True)
-    assert int(got) - 256 == int(want)
+    assert int(got[0]) - 256 == int(want)
 
 
 @pytest.mark.parametrize("nbins", [4, 16, 60])
@@ -85,7 +87,7 @@ def test_pair_hist_sweep(nbins):
     got = pair_hist_masked_pallas(a, b, na, nb, edges, tm=256, tn=256,
                                   interpret=True)
     want = pair_hist_ref(a[0], b[0], edges)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got)[0], np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -141,7 +143,11 @@ def test_pair_count_masked_ragged(P, C1, C2, n_o, n_b, radius):
                                       interpret=True)
     got_ref = pair_count_masked_ref(a, b, no, nb, cmin)
     got_blk = pair_count_blocked(a, b, no, nb, cmin)
-    assert int(got_pl) == want and int(got_ref) == want, (got_pl, want)
+    assert [int(g) for g in got_pl] == [
+        _loop_count(a[p:p + 1], b[p:p + 1], n_o[p:p + 1], n_b[p:p + 1], cmin)
+        for p in range(P)], got_pl
+    assert int(np.sum(got_pl)) == want and int(got_ref) == want, (got_pl,
+                                                                   want)
     assert int(got_blk) == want, (got_blk, want)
 
 
@@ -157,7 +163,8 @@ def test_pair_hist_masked_ragged(P, C1, C2, n_o, n_b, nbins):
                                      interpret=True)
     got_ref = pair_hist_masked_ref(a, b, no, nb, edges)
     got_blk = pair_hist_blocked(a, b, no, nb, edges)
-    np.testing.assert_array_equal(np.asarray(got_pl, np.int64), want)
+    np.testing.assert_array_equal(np.asarray(got_pl, np.int64).sum(axis=0),
+                                  want)
     np.testing.assert_array_equal(np.asarray(got_ref, np.int64), want)
     np.testing.assert_array_equal(np.asarray(got_blk, np.int64), want)
 
@@ -168,11 +175,11 @@ def _masked_pallas_and_ref(kind, a, b, no, nb):
     if kind == "count":
         cmin = float(np.cos(0.3))
         return (lambda: pair_count_masked_pallas(a, b, no, nb, cmin, tm=32,
-                                                 tn=32, interpret=True),
+                                                 tn=32, interpret=True).sum(),
                 pair_count_masked_ref(a, b, no, nb, cmin))
     edges = jnp.asarray(np.cos(np.linspace(0.05, 0.4, 5)), jnp.float32)
     return (lambda: pair_hist_masked_pallas(a, b, no, nb, edges, tm=32,
-                                            tn=32, interpret=True),
+                                            tn=32, interpret=True).sum(0),
             pair_hist_masked_ref(a, b, no, nb, edges))
 
 
@@ -257,3 +264,16 @@ def test_flash_custom_vjp_backward(rng):
                                                     None, False)))(q)
     g2 = jax.grad(lambda q: jnp.sum(attention_ref(q, k, v, causal=True)))(q)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-5)
+
+
+def test_wide_sum_is_exact_past_int32():
+    """Pair counts summed over partitions, tiers and chips pass int32 at
+    survey scale: the base-2^16 digits stay exact through further sums."""
+    from repro.kernels.zones_pairs.ops import wide_sum, wide_value
+    c = jnp.full((8, 3), 2**31 - 1, jnp.int32).at[:, 1].set(123_456_789)
+    d = wide_sum(c)
+    assert d.dtype == jnp.int32 and d.shape == (2, 3)
+    twice = d + d                    # a second tier, or a psum of two chips
+    np.testing.assert_array_equal(
+        wide_value(twice), 16 * np.array([2**31 - 1, 123_456_789, 2**31 - 1],
+                                         np.int64))

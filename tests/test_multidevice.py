@@ -56,3 +56,13 @@ def test_mapreduce_lanes_multidevice():
     """Per-device concurrent lanes across 8 host devices == monolithic,
     with and without injected chaos (delays, transient faults, clones)."""
     assert "OK" in _run("mapreduce-lanes")
+
+
+@pytest.mark.slow
+def test_mapreduce_cluster_shuffle_multidevice():
+    """Four-chip cluster shuffle (4 host devices): per-chip map, one
+    all-to-all to the partition owners, tiers born sharded. Bit-identical
+    to the one-device path and the host engine; each chip's tier slices
+    hold the one-device rows of its partitions; the exchange's byte
+    counter equals the entries that left their home chip."""
+    assert "OK" in _run("mapreduce-cluster", timeout=300)

@@ -252,6 +252,29 @@ def test_profiler_session_records_device_job_spans(tmp_path):
     assert job_stats["jax_compile_s"] > 0
 
 
+def test_span_counters_reach_chrome_args_and_profiler_stats(tmp_path):
+    """A span's own counters (the cluster shuffle's exchange bytes and
+    rows) land in the Chrome event's args and, under a profiler session,
+    in the ``mr:*`` event's stats, from either tracer."""
+    import jax
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("shuffle.exchange",
+                     counters={"exchange_bytes": 120, "exchange_rows": 10}):
+            pass
+        with trace_mod.NullTracer().span("quiet",
+                                         counters={"exchange_rows": 3}):
+            pass
+    (ev,) = [e for e in tr.events if e["name"] == "shuffle.exchange"]
+    assert ev["args"]["exchange_bytes"] == 120
+    assert ev["args"]["exchange_rows"] == 10
+    spans = _profiled_spans(str(tmp_path))
+    ((_, _, stats),) = spans["mr:shuffle.exchange"]
+    assert (stats["exchange_bytes"], stats["exchange_rows"]) == (120, 10)
+    ((_, _, stats),) = spans["mr:quiet"]
+    assert stats["exchange_rows"] == 3
+
+
 def test_compile_counters_nest_and_tell_cache_hits_from_compiles():
     import jax
     trace_dur = "/jax/core/compile/jaxpr_trace_duration"
