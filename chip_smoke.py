@@ -17,8 +17,8 @@ search radii and one four-edge histogram). Phases on one chip:
     every answer equal to (a) for the same job;
 (d) correctness: at 20,000 rows the search count equals
     ``sky.brute_force_pairs`` (numpy); at full N every answer of (a) equals
-    the z-banded blocked engine (``use_pallas=False``), which shares no code
-    with the Pallas kernels.
+    the blocked engine (``use_pallas=False``), which shares no scoring code
+    with the Pallas kernels (only the box test that skips tile pairs).
 
 ``--chips 4`` runs (a) and (c) under ``make_mesh((4,), ("data",))`` and
 compares them with the same jobs at ``mesh=None`` on device 0.

@@ -1,24 +1,20 @@
-"""Z-banded blocked reduce: the CPU/XLA twin of the masked Pallas grid.
+"""Blocked reduce: the CPU/XLA twin of the masked Pallas grid.
 
-The masked-batched kernels cover every (owned-tile, bucket-tile) pair of a
-partition. For the Zones algorithm that is wasteful: a within-radius pair
-satisfies ``|z_i - z_j| <= |v_i - v_j| <= sqrt(2*max_norm^2 - 2*cos_min)``,
-so tile pairs whose z-ranges are further apart than that bound *cannot*
-contain a hit and can be skipped outright. This module:
+The masked-batched kernels would cover every (owned-tile, bucket-tile) pair
+of a partition. Both engines instead skip the tile pairs whose boxes lie
+out of reach of the widest edge (``windows.box_keep``: per-tile bounds of
+the real rows, an exact test, so results match the dense masked reference
+bit-for-bit — property-checked in ``tests/test_kernels.py``). The shuffle
+orders each zone by RA, so the boxes are short and most tile pairs go.
+This module:
 
 1. chops every partition of a [P, C, 3] tier into fixed TM/TN-row tiles and
-   computes per-tile z ranges on device (padding rows masked out),
-2. prunes tile pairs with the (conservative, codec-error-aware) z-gap bound
-   on the host — index metadata only, a [P, gm, gn] boolean,
-3. gathers the surviving tile pairs into a block stream and reduces it in
+   runs the box test on device — index metadata only, a [P, gm, gn]
+   boolean brought to the host,
+2. gathers the surviving tile pairs into a block stream and reduces it in
    fixed-shape chunks ([B0, TM, 3] x [B0, TN, 3]) through ONE jitted masked
    kernel, so the expensive XLA compile happens once per process instead of
    once per job shape.
-
-The pruning bound is exact: a skipped tile pair provably contains no dot
-``>= cos_min`` even after f32 rounding (the slack term covers it), so
-blocked results match the dense masked reference bit-for-bit — this is
-property-checked in ``tests/test_kernels.py``.
 
 Chunk geometry: TM=TN=64 rows (falls back to the largest divisor of the
 capacity), B0=512 blocks per chunk — ~2M score cells per dispatch, enough
@@ -30,20 +26,19 @@ bit-identical for every shape.
 """
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.zones_pairs import windows
 from repro.kernels.zones_pairs.kernel import _fit_tile
 from repro.kernels.zones_pairs.ref import _batched_dots, _pair_mask
 
 TM = 64           # tile rows (owned side)
 TN = 64           # tile rows (bucket side)
 B0 = 512          # blocks per kernel dispatch (fixed -> one compile)
-_SLACK = 1e-3     # covers f32 rounding in dots/ranges/threshold
 
 # chunk-shape resolution: hand-tuned module constants by default; an explicit
 # override (tests / power users) wins; REPRO_AUTO_CHUNK=1 asks the cost
@@ -100,40 +95,20 @@ def _hist_chunk(a, b, na, nb, cos_edges):
                              jnp.zeros(cos_edges.shape, jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("gm", "tm"))
-def _tile_ranges(x, n_rows, *, gm, tm):
-    """Per-tile z min/max + max squared norm, padding rows masked.
-    x: [P, C, 3], n_rows: [P] -> (zmin [P,gm], zmax [P,gm], max_norm2)."""
-    P = x.shape[0]
-    z = x[..., 2].reshape(P, gm, tm)
-    n2 = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1).reshape(P, gm, tm)
-    row = jnp.arange(gm * tm, dtype=jnp.int32).reshape(gm, tm)
-    valid = row[None] < n_rows[:, None, None]
-    zmin = jnp.min(jnp.where(valid, z, jnp.inf), axis=-1)
-    zmax = jnp.max(jnp.where(valid, z, -jnp.inf), axis=-1)
-    mn2 = jnp.max(jnp.where(valid, n2, 0.0))
-    return zmin, zmax, mn2
+_box_keep = jax.jit(windows.box_keep, static_argnames=("tm", "tn"))
 
 
 def _plan_blocks(a, b, n_a, n_b, cos_min, tm0=None, tn0=None):
     """-> (a_tile_idx, b_tile_idx, na_blk, nb_blk) numpy arrays of surviving
-    tile pairs, plus (gm, tm, gn, tn). Empty tiles and z-gap-pruned tile
-    pairs are dropped."""
+    tile pairs, plus (gm, tm, gn, tn). Empty tiles and tile pairs out of
+    reach (``windows.box_keep``) are dropped."""
     P, C1, _ = a.shape
     C2 = b.shape[1]
     tm = _fit_tile(C1, TM if tm0 is None else tm0)
     tn = _fit_tile(C2, TN if tn0 is None else tn0)
     gm, gn = C1 // tm, C2 // tn
-    azmin, azmax, amn2, bzmin, bzmax, bmn2 = jax.device_get(
-        _tile_ranges(a, n_a, gm=gm, tm=tm)
-        + _tile_ranges(b, n_b, gm=gn, tm=tn))    # one host sync
-    mn2 = float(max(amn2, bmn2))
-    # |z_i - z_j| > sqrt(|v_i|^2 + |v_j|^2 - 2*cos_min)  =>  dot < cos_min
-    thresh = float(np.sqrt(max(2.0 * mn2 - 2.0 * float(cos_min), 0.0))
-                   ) + _SLACK
-    gap = np.maximum(bzmin[:, None, :] - azmax[:, :, None],
-                     azmin[:, :, None] - bzmax[:, None, :])   # [P, gm, gn]
-    pi, ii, jj = np.nonzero(gap <= thresh)    # empty tiles: gap == +inf
+    keep = np.asarray(_box_keep(a, b, n_a, n_b, cos_min, tm=tm, tn=tn))
+    pi, ii, jj = np.nonzero(keep)
     na_blk = np.clip(np.asarray(n_a)[pi] - ii * tm, 0, tm).astype(np.int32)
     nb_blk = np.clip(np.asarray(n_b)[pi] - jj * tn, 0, tn).astype(np.int32)
     return ((pi * gm + ii).astype(np.int32), (pi * gn + jj).astype(np.int32),
@@ -156,12 +131,17 @@ def _gather_chunk(fa, fb, ai, bi, na, nb, k):
 
 
 def _run_blocked(a, b, n_a, n_b, cos_min, chunk_fn, chunk_arg, out0):
+    """-> (the summed chunk results, [2] int32 tile pairs: scored, and with
+    a real row on both sides)."""
     tm0, tn0, b0 = chunk_shape()
     ai, bi, na_blk, nb_blk, (gm, tm, gn, tn) = _plan_blocks(
         a, b, n_a, n_b, cos_min, tm0, tn0)
     nblk = len(ai)
+    real = int(np.sum(windows.real_tiles(np.asarray(n_a, np.int64),
+                                         np.asarray(n_b, np.int64), tm, tn)))
+    tiles = jnp.asarray([nblk, real], jnp.int32)
     if not nblk:              # everything pruned or empty
-        return out0
+        return out0, tiles
     pad = (-nblk) % b0
     if pad:   # padded blocks point at tile 0 with zero-row masks
         z = np.zeros(pad, np.int32)
@@ -189,26 +169,22 @@ def _run_blocked(a, b, n_a, n_b, cos_min, chunk_fn, chunk_arg, out0):
     out = out0
     for k in range(nchunks):   # dynamic index: one compiled slice per shape
         out = out + chunk_fn(*pick(jnp.int32(k)), chunk_arg)
-    return out
+    return out, tiles
 
 
 def pair_count_blocked(a, b, n_a, n_b, cos_min):
-    """Z-banded blocked twin of ``pair_count_masked_ref`` ([P,C1,3] x
-    [P,C2,3] + real counts -> total int32). Exact same result; skips tile
-    pairs that provably cannot contain a within-threshold pair."""
-    if a.shape[-1] != 3:   # pruning bound assumes 3D unit-ish vectors
-        from repro.kernels.zones_pairs.ref import pair_count_masked_ref
-        return pair_count_masked_ref(a, b, n_a, n_b, cos_min)
+    """Blocked twin of ``pair_count_masked_ref`` ([P,C1,d] x [P,C2,d] + real
+    counts -> (total int32, [2] tile pairs as ``_run_blocked``)). Exact same
+    result; skips tile pairs that provably cannot contain a within-threshold
+    pair."""
     return _run_blocked(a, b, n_a, n_b, cos_min, _count_chunk,
                         jnp.float32(cos_min), jnp.int32(0))
 
 
 def pair_hist_blocked(a, b, n_a, n_b, cos_edges):
-    """Z-banded blocked twin of ``pair_hist_masked_ref`` (cumulative counts
-    per cos edge, edges descending in cos). Pruning uses the loosest edge."""
-    if a.shape[-1] != 3:
-        from repro.kernels.zones_pairs.ref import pair_hist_masked_ref
-        return pair_hist_masked_ref(a, b, n_a, n_b, cos_edges)
+    """Blocked twin of ``pair_hist_masked_ref`` (cumulative counts per cos
+    edge, edges descending in cos; and the tile pairs). Skipping uses the
+    loosest edge."""
     edges = jnp.asarray(cos_edges, jnp.float32)
     cos_min = float(jnp.min(edges))
     return _run_blocked(a, b, n_a, n_b, cos_min, _hist_chunk, edges,

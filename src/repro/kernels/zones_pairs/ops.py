@@ -7,10 +7,12 @@ under test.
 ``pair_count_masked``/``pair_hist_masked`` are the engine="device" batched
 reduce: one call covers a whole size tier of partitions, padded rows masked
 via n_a/n_b, never via pad-value tricks. On TPU: the Pallas kernels with a
-leading partition grid axis. Elsewhere: the z-banded blocked reduce
-(``blocked.py``) — same results, tile pairs outside the z band pruned,
-fixed-shape chunks so the XLA compile is shared across codecs, radii, and
-job shapes. The blocked path runs eagerly (it plans its blocks on the
+leading partition grid axis. Elsewhere: the blocked reduce
+(``blocked.py``) — same results, fixed-shape chunks so the XLA compile is
+shared across codecs, radii, and job shapes. Both skip the tile pairs that
+the box test (``windows.py``) proves hold no hit, and both return a
+``PairTotals``: the counts, and the tile pairs scored beside those of real
+rows. The blocked path runs eagerly (it plans its blocks on the
 host), NOT under jit. The Pallas path builds one Pallas call per tier
 shape and keeps it (``kernel._hist_call``), so it traces and lowers once
 per shape and each later call of that shape dispatches a cached program.
@@ -36,7 +38,7 @@ eagerly. Interpret mode is reachable only through an explicit
 """
 from __future__ import annotations
 
-import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +47,17 @@ import numpy as np
 from repro.kernels.zones_pairs.kernel import (pair_count_masked_pallas,
                                               pair_hist_masked_pallas)
 from repro.kernels.zones_pairs.ref import pair_count_ref, pair_hist_ref
+
+
+class PairTotals(NamedTuple):
+    """A pair reduction's total: ``counts`` as ``wide_sum`` digits, and
+    ``tiles``, [2] int32, the tile pairs its kernel scored and those with a
+    real row on both sides (their ratio is how much the box test left to
+    score; the host engine scores no tiles and reports zeros). A pytree, so
+    tiers, splits and chips add it up leaf by leaf."""
+
+    counts: jax.Array
+    tiles: jax.Array
 
 
 def _on_tpu() -> bool:
@@ -63,6 +76,20 @@ def wide_sum(counts):
                       jnp.sum(counts & 0xFFFF, axis=0, dtype=jnp.int32)])
 
 
+@jax.jit
+def _pair_totals(counts, tiles):
+    """Per-partition counts ``[P, ...]`` and tile pairs ``[P, 2]`` ->
+    ``PairTotals`` of the tier, in one dispatch."""
+    return PairTotals(wide_sum(counts), jnp.sum(tiles, axis=0,
+                                                  dtype=jnp.int32))
+
+
+def no_tiles(counts) -> PairTotals:
+    """``PairTotals`` of a reduction that scores no tiles (the host
+    engine's per-partition oracle)."""
+    return PairTotals(counts, jnp.zeros((2,), jnp.int32))
+
+
 def wide_value(digits) -> np.ndarray:
     """``wide_sum`` digits (summed any number of times) -> int64 values."""
     d = np.asarray(digits, np.int64)
@@ -71,27 +98,29 @@ def wide_value(digits) -> np.ndarray:
 
 def masked_uses_pallas(use_pallas: bool | None = None) -> bool:
     """Resolve a ``use_pallas`` setting: True -> traceable Pallas masked
-    kernels, False -> the eager-only z-banded blocked engine."""
+    kernels, False -> the eager-only blocked engine."""
     return _on_tpu() if use_pallas is None else use_pallas
 
 
 def pair_count_masked(a, b, n_a, n_b, cos_min, *,
-                      use_pallas: bool | None = None):
-    """-> the tier's pair count as ``wide_sum`` digits, ``[2]``."""
+                      use_pallas: bool | None = None) -> PairTotals:
+    """-> the tier's pair count: ``PairTotals`` with ``[2]`` digits."""
     if masked_uses_pallas(use_pallas):
-        return wide_sum(pair_count_masked_pallas(
+        return _pair_totals(*pair_count_masked_pallas(
             a, b, n_a, n_b, cos_min, interpret=not _on_tpu()))
     from repro.kernels.zones_pairs.blocked import pair_count_blocked
-    return wide_sum(pair_count_blocked(a, b, n_a, n_b, cos_min)[None])
+    count, tiles = pair_count_blocked(a, b, n_a, n_b, cos_min)
+    return _pair_totals(count[None], tiles[None])
 
 
 def pair_hist_masked(a, b, n_a, n_b, cos_edges, *,
-                     use_pallas: bool | None = None):
-    """-> the tier's cumulative counts as ``wide_sum`` digits, ``[2, NB]``
-    (the blocked engine's total, an int32 sum, is exact below 2^31: the
-    sizes it runs at off the chip)."""
+                     use_pallas: bool | None = None) -> PairTotals:
+    """-> the tier's cumulative counts: ``PairTotals`` with ``[2, NB]``
+    digits (the blocked engine's total, an int32 sum, is exact below 2^31:
+    the sizes it runs at off the chip)."""
     if masked_uses_pallas(use_pallas):
-        return wide_sum(pair_hist_masked_pallas(
+        return _pair_totals(*pair_hist_masked_pallas(
             a, b, n_a, n_b, cos_edges, interpret=not _on_tpu()))
     from repro.kernels.zones_pairs.blocked import pair_hist_blocked
-    return wide_sum(pair_hist_blocked(a, b, n_a, n_b, cos_edges)[None])
+    hist, tiles = pair_hist_blocked(a, b, n_a, n_b, cos_edges)
+    return _pair_totals(hist[None], tiles[None])

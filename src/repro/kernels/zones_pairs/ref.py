@@ -6,7 +6,7 @@ import jax.numpy as jnp
 
 def _dots2d(a, b):
     """[M,d] x [N,d] -> [M,N] scores as an unrolled broadcast sum. Every
-    engine path (host lax.map, masked-batched, z-banded blocked) shares this
+    engine path (host lax.map, masked-batched, blocked) shares this
     formulation so scores agree bit-for-bit: XLA lowers a d=3 dot_general
     with FMA (no intermediate rounding), which differs in the last ulp from
     the rounded product sum and would flip pairs sitting exactly on a

@@ -58,6 +58,11 @@ class StageStats:
     reduce_flops: float = 0.0
     reduce_bytes: int = 0              # bytes streamed by the reduce kernels
     reduce_padded_ratio: float = 1.0   # padded / real pair cells (capacity waste)
+    # tile pairs the pair kernels scored (inside their windows) and those
+    # with a real row on both sides; read only while a trace records
+    # (``obs.trace.recording``), 0 otherwise
+    pair_tiles_scored: int = 0
+    pair_tiles_real: int = 0
     # per-shard padded/real pair-cell ratios, length n_shards (a shard of
     # pure phantom padding shows its full padded cell count — load imbalance
     # and phantom waste in one vector; empty () off the MapReduce engines)
@@ -114,6 +119,7 @@ class StageStats:
                      "shuffle_wire_bytes", "shuffle_raw_bytes",
                      "exchange_rows", "exchange_bytes",
                      "reduce_wall_s", "reduce_flops", "reduce_bytes",
+                     "pair_tiles_scored", "pair_tiles_real",
                      "fetch_wall_s", "combine_wall_s", "overlap_hidden_s",
                      "spill_bytes", "spill_wall_s", "spilled_splits",
                      "speculated", "clone_wins", "retries",

@@ -18,7 +18,7 @@ composed into a ``MapReduceJob`` and executed by one of two engines:
   Partitions are grouped into size tiers (``plan_tiers``) so one skewed
   partition doesn't inflate every partition's capacity padding, and each
   tier reduces through batched masked kernels (``pair_count_masked`` & co.:
-  Pallas partition-grid kernels on TPU, the z-banded blocked engine
+  Pallas partition-grid kernels on TPU, the blocked engine
   elsewhere) instead of a sequential ``lax.map``. Under a ``data``-axis
   mesh the job runs as a cluster: each chip maps its own contiguous block
   of the rows (``map_blocks``), one all-to-all moves every owned row and
@@ -58,7 +58,7 @@ from repro.core.compat import shard_map as _shard_map_compat
 from repro.mapreduce.codecs import ShuffleCodec, get_codec
 from repro.mapreduce.instrumentation import StageStats
 from repro.obs.energy import get_meter
-from repro.obs.trace import get_tracer
+from repro.obs.trace import get_tracer, recording
 
 
 def _round_up(x: int, m: int) -> int:
@@ -115,10 +115,10 @@ class Partitioner:
 
     def sort_key_device(self, items):
         """Optional [n] secondary sort key: rows within a partition land in
-        this order, which tightens the per-tile ranges the z-banded blocked
-        reduce prunes on (``ZonePartitioner`` returns z). Order never
-        affects results — partition reductions are commutative sums — so
-        ``None`` (arrival order) is always correct."""
+        this order, which tightens the per-tile boxes the pair reduces skip
+        by (``ZonePartitioner`` returns RA). Order never affects results —
+        partition reductions are commutative sums — so ``None`` (arrival
+        order) is always correct."""
         return None
 
     def bucket_entries_device(self, items, keys, n_parts: int):
@@ -199,10 +199,15 @@ class Reducer:
     def reduce_traceable(self) -> bool:
         """Whether ``reduce_partitions`` is pure traced jax — callable inside
         a ``shard_map`` region. The default masked ``lax.map`` is; reducers
-        that delegate to the z-banded blocked engine (host-side block
+        that delegate to the blocked engine (host-side block
         planning) are not, and the sharded reduce falls back to eager
         per-shard slicing with a psum combine of the partials."""
         return True
+
+    def tile_pairs(self, total):
+        """The ``[2]`` tile pairs (scored, real) inside a device-engine
+        total, for a reducer whose kernel skips tile pairs; else None."""
+        return None
 
     def finalize(self, total, sd: "ShuffledData"):
         """Host-side post-combine (dedup corrections, differencing, ...)."""
@@ -441,13 +446,14 @@ def reduce_stage(reducers, sd: ShuffledData, mesh=None):
 
     if _data_axis_size(mesh) == 1:
         outs = jax.lax.map(lambda ab: per_part(ab[0], ab[1]), (owned, bucket))
-        return tuple(jnp.sum(o, axis=0) for o in outs)
+        return jax.tree.map(lambda o: jnp.sum(o, axis=0), outs)
 
     from jax.sharding import PartitionSpec as P
 
     def body(o, b):
         r = jax.lax.map(lambda ab: per_part(ab[0], ab[1]), (o, b))
-        return tuple(jax.lax.psum(jnp.sum(x, axis=0), "data") for x in r)
+        return jax.tree.map(lambda x: jax.lax.psum(jnp.sum(x, axis=0),
+                                                   "data"), r)
 
     D = _data_axis_size(mesh)
     assert owned.shape[0] % D == 0, (owned.shape, dict(mesh.shape))
@@ -573,26 +579,45 @@ def plan_tiers(n_owned, n_bucket, tile: int, max_tiers: int = 3,
     return build(best_cuts)
 
 
+def _sort_keys(ids, skey, n_ids: int):
+    """One int32 sort key per entry: the partition id (``0..n_ids - 1``) in
+    the high bits, ``skey`` quantised linearly over its range in the
+    ``B`` bits below, where ``n_ids * 2^B <= 2^31``. Quantising is monotone,
+    so an argsort orders each partition by ``skey`` (ties in arrival
+    order)."""
+    bits = 31 - max(n_ids - 1, 1).bit_length()
+    lo, hi = jnp.min(skey), jnp.max(skey)
+    scale = (2.0 ** bits - 1.0) / jnp.maximum(hi - lo, 1e-30)
+    q = jnp.floor((skey - lo) * scale).astype(jnp.int32)   # float32 rounds
+    return (ids << bits) | jnp.clip(q, 0, 2 ** bits - 1)    # 2^B - 1 up
+
+
 @functools.partial(jax.jit, static_argnames=("specs",))
-def _scatter_tiers_jit(payloads, keys, dest_eff, src, owned_starts,
+def _scatter_tiers_jit(payloads, keys, dest_eff, src, skey, owned_starts,
                        bucket_starts, part_tier, part_local, *, specs):
-    """Argsort-based bucketing: sort bucket entries by destination, compute
-    each entry's rank within its partition from the exclusive-cumsum
-    starts, and scatter the *wire-dtype* payload rows into every tier's
-    padded [Pt, C, ...] layout (entries outside the tier drop out of
-    range).
+    """Argsort-based bucketing: sort owned rows by partition and bucket
+    entries by destination, compute each entry's rank within its partition
+    from the exclusive-cumsum starts, and scatter the *wire-dtype* payload
+    rows into every tier's padded [Pt, C, ...] layout (entries outside the
+    tier drop out of range).
 
     ``dest_eff`` is [m] with invalid entries set to P (they sort last and
     hit ``part_tier[P] == -1``, so no tier claims them).
 
-    Rows keep arrival order within a partition: the partitioner's secondary
-    sort key only tightens the blocked reduce's z pruning, which the Pallas
-    reduce of this (accelerator) path does not do, and a two-key sort costs
-    the TPU compiler ~100 s more than a one-key argsort.
+    Within a partition, rows and bucket entries lie in the order of the
+    partitioner's secondary key ``skey`` (RA for zones, so the pair kernel's
+    tile windows are short), or in arrival order where it has none. Each is
+    ONE argsort of an int32 key (``_sort_keys``): a two-key sort costs the
+    TPU compiler ~100 s more than a one-key argsort.
     """
     n, m = keys.shape[0], dest_eff.shape[0]
-    ko = jnp.argsort(keys)
-    bo = jnp.argsort(dest_eff)
+    if skey is None or not n:
+        ko = jnp.argsort(keys)
+        bo = jnp.argsort(dest_eff)
+    else:
+        n_ids = owned_starts.shape[0]             # P partitions + the P slot
+        ko = jnp.argsort(_sort_keys(keys, skey, n_ids))
+        bo = jnp.argsort(_sort_keys(dest_eff, skey[src], n_ids))
     sk = keys[ko]
     orank = jnp.arange(n, dtype=jnp.int32) - owned_starts[sk]
     sd = dest_eff[bo]
@@ -625,9 +650,11 @@ def _scatter_tiers_jit(payloads, keys, dest_eff, src, owned_starts,
 # The RESOLVED choice is recorded in ``StageStats.shuffle_index_impl``
 # ("jnp" | "host") so an "auto" run under a mesh is never ambiguous about
 # which path produced its shuffle metadata; both paths must produce
-# identical tier shapes and results (asserted in tests and md_check). Row
-# order within a partition differs: only the host path sorts by the
-# partitioner's secondary key (see ``_scatter_tiers_jit``).
+# identical tier shapes and results (asserted in tests and md_check). Both
+# order each partition by the partitioner's secondary key: the host path by
+# the key itself, the jnp path by the key quantised to the bits the
+# partition id leaves free (``_sort_keys``), so rows whose keys share a
+# quantum may lie in another order.
 SHUFFLE_INDEX_IMPL = "auto"            # "auto" | "jnp" | "host"
 
 
@@ -876,7 +903,7 @@ def _reduce_tier_sharded(reducers, codec, tier: TierData, mesh):
     - every reducer traceable (Pallas masked kernels on TPU, pure-jnp
       reducers anywhere): decode + masked reduce + ``lax.psum`` run INSIDE
       one ``shard_map`` region, each shard's kernels on its own device.
-    - otherwise (the z-banded blocked engine plans its blocks on the host,
+    - otherwise (the blocked engine plans its blocks on the host,
       which cannot happen under tracing): each shard's rows are sliced and
       reduced eagerly, then the stacked per-shard partials cross ONE
       ``shard_map`` psum. Bit-identical either way — the accumulators are
@@ -1067,11 +1094,17 @@ class ResidentCatalog:
         """Tiered masked reduce of ``reducers`` over the resident tiers —
         the reduce half of ``shuffle_reduce_device``, with the same
         accumulate (``+=``) stats contract. Decode happens on-device per
-        pass; under a data-axis mesh each tier reduces psum-sharded."""
+        pass; under a data-axis mesh each tier reduces psum-sharded.
+
+        While anything records (``obs.trace.recording``), the reducers'
+        tile pairs (``Reducer.tile_pairs``) are read once the reduce is
+        done, into ``stats.pair_tiles_*`` and as counters of the ``reduce``
+        span; otherwise they stay on the device, costing no transfer."""
         D = _data_axis_size(self.mesh)
         tr = get_tracer()
+        tiles = {}
         with tr.span("reduce", cat="stage", engine="device",
-                     tiers=len(self.sd.tiers)):
+                     tiers=len(self.sd.tiers), counters=tiles):
             t0 = time.perf_counter()
             totals = None
             with tr.span("reduce.dispatch", cat="stage"):
@@ -1092,6 +1125,10 @@ class ResidentCatalog:
             with tr.span("reduce.wait", cat="stage"):
                 totals = jax.block_until_ready(totals)
             t1 = time.perf_counter()
+            if recording():
+                tiles.update(_tile_pairs(reducers, totals))
+        stats.pair_tiles_scored += tiles.get("pair_tiles_scored", 0)
+        stats.pair_tiles_real += tiles.get("pair_tiles_real", 0)
         stats.reduce_wall_s += t1 - t0
         stats.reduce_bytes += self.nbytes
         flops = float(sum(r.flops(self.sd) for r in reducers))
@@ -1131,6 +1168,17 @@ class ResidentCatalog:
         meter.attribute(mtok, stats)
         return [JobResult(j.reducer.finalize(t, self.sd), stats)
                 for j, t in zip(jobs, totals)]
+
+
+def _tile_pairs(reducers, totals) -> dict:
+    """The tile pairs the reducers' kernels scored and those of real rows,
+    summed over reducers (one host transfer); {} where none reports any."""
+    got = [t for t in (r.tile_pairs(tot) for r, tot in zip(reducers, totals))
+           if t is not None]
+    if not got:
+        return {}
+    scored, real = np.sum(np.asarray(jax.device_get(got), np.int64), axis=0)
+    return {"pair_tiles_scored": int(scored), "pair_tiles_real": int(real)}
 
 
 def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
@@ -1228,7 +1276,7 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
             with tr.span("shuffle.scatter", cat="stage"):
                 if _use_jnp_indices():
                     scattered = _scatter_tiers_jit(
-                        m.payloads, m.keys, m.dest_eff, m.src,
+                        m.payloads, m.keys, m.dest_eff, m.src, m.skey,
                         jnp.asarray(o_starts), jnp.asarray(b_starts),
                         jnp.asarray(part_tier), jnp.asarray(part_local),
                         specs=specs)

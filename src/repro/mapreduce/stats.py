@@ -18,8 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.sky import ARCSEC
-from repro.kernels.zones_pairs.ops import (pair_hist, pair_hist_masked,
-                                           wide_sum, wide_value)
+from repro.kernels.zones_pairs.ops import (no_tiles, pair_hist,
+                                           pair_hist_masked, wide_sum,
+                                           wide_value)
 from repro.mapreduce.job import MapReduceJob, Reducer, ShuffledData, run_job
 from repro.mapreduce.zones import ZonePartitioner
 
@@ -28,7 +29,7 @@ DEFAULT_EDGES_ARCSEC = tuple(float(e) for e in range(1, 61))
 
 @dataclasses.dataclass(frozen=True)
 class PairHistReducer(Reducer):
-    """Cumulative per-edge pair counts per zone, as ``wide_sum`` digits;
+    """Cumulative per-edge pair counts per zone, as ``PairTotals``;
     finalize differentiates."""
 
     edges_rad: tuple
@@ -38,7 +39,8 @@ class PairHistReducer(Reducer):
         return jnp.asarray(np.cos(np.asarray(self.edges_rad)), jnp.float32)
 
     def per_partition(self, owned_p, bucket_p):
-        return wide_sum(pair_hist(owned_p, bucket_p, self._cos_edges())[None])
+        return no_tiles(wide_sum(
+            pair_hist(owned_p, bucket_p, self._cos_edges())[None]))
 
     def reduce_partitions(self, owned, bucket, n_owned, n_bucket):
         return pair_hist_masked(owned, bucket, n_owned, n_bucket,
@@ -49,8 +51,11 @@ class PairHistReducer(Reducer):
         from repro.kernels.zones_pairs.ops import masked_uses_pallas
         return masked_uses_pallas(self.use_pallas)
 
+    def tile_pairs(self, total):
+        return total.tiles
+
     def finalize(self, total, sd: ShuffledData):
-        cum = wide_value(total)
+        cum = wide_value(total.counts)
         cum -= int(sd.n_owned.sum())   # self pairs (theta=0) hit every edge
         cum //= 2                      # each unordered pair seen twice
         return np.diff(np.concatenate([[0], cum]))

@@ -23,8 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data import sky
-from repro.kernels.zones_pairs.ops import (pair_count, pair_count_masked,
-                                           wide_sum, wide_value)
+from repro.kernels.zones_pairs.ops import (no_tiles, pair_count,
+                                           pair_count_masked, wide_sum,
+                                           wide_value)
 from repro.mapreduce.job import (MapReduceJob, Partitioner, Reducer,
                                  ShuffledData, run_job)
 
@@ -86,9 +87,11 @@ class ZonePartitioner(Partitioner):
                         0, Z - 1)
 
     def sort_key_device(self, items):
-        # z-order within each zone: tight per-tile z ranges for the banded
-        # blocked reduce (order never changes results, only pruning power)
-        return items[:, 2]
+        # RA order within each zone: a zone is a band in declination, so
+        # rows near each other in RA are near each other on the sky, and a
+        # tile's box is short (order never changes results, only how many
+        # tile pairs the reduce skips)
+        return jnp.arctan2(items[:, 1], items[:, 0])
 
     def bucket_entries_device(self, items, keys, n_parts):
         h, margin = self.height, self.radius + REPLICA_EPS
@@ -108,15 +111,15 @@ class ZonePartitioner(Partitioner):
 
 @dataclasses.dataclass(frozen=True)
 class PairCountReducer(Reducer):
-    """Blockwise within-radius pair count per zone, as ``wide_sum`` digits;
+    """Blockwise within-radius pair count per zone, as ``PairTotals``;
     finalize removes self pairs and the double-count."""
 
     radius: float
     use_pallas: bool | None = None
 
     def per_partition(self, owned_p, bucket_p):
-        return wide_sum(pair_count(owned_p, bucket_p,
-                                   float(np.cos(self.radius)))[None])
+        return no_tiles(wide_sum(pair_count(owned_p, bucket_p,
+                                            float(np.cos(self.radius)))[None]))
 
     def reduce_partitions(self, owned, bucket, n_owned, n_bucket):
         return pair_count_masked(owned, bucket, n_owned, n_bucket,
@@ -127,8 +130,11 @@ class PairCountReducer(Reducer):
         from repro.kernels.zones_pairs.ops import masked_uses_pallas
         return masked_uses_pallas(self.use_pallas)
 
+    def tile_pairs(self, total):
+        return total.tiles
+
     def finalize(self, total, sd: ShuffledData):
-        return (int(wide_value(total)) - int(sd.n_owned.sum())) // 2
+        return (int(wide_value(total.counts)) - int(sd.n_owned.sum())) // 2
 
     def flops(self, sd: ShuffledData):
         # per zone: C1*C2 dot products (2*3 FLOPs) + compares

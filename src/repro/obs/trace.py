@@ -30,9 +30,12 @@ not serve) and ``jax_compile_s`` (the three durations summed, cache reads
 included). They go to the profiler event's stats always, and to the Chrome
 event's ``args`` where any is non-zero. The listener is registered the
 first time such a span opens, and never in a run without one. A span may
-also carry counters of its own, known when it opens (``span(name,
-counters={...})``: the cluster shuffle's ``exchange_bytes`` and
-``exchange_rows``); they go to the same two places, always.
+also carry counters of its own (``span(name, counters={...})``: the
+cluster shuffle's ``exchange_bytes`` and ``exchange_rows``, the reduce's
+``pair_tiles_scored`` and ``pair_tiles_real``); the dict is read when the
+span closes, so the code inside may fill it in, and they go to the same two
+places, always. ``recording()`` says whether anything records: a counter
+that costs a device transfer is read only then.
 
 The module-level current tracer defaults to ``NullTracer`` whose
 ``span()`` / ``ids()`` return a shared reentrant no-op context manager,
@@ -140,7 +143,7 @@ class _ProfiledSpan:
 
     def __init__(self, name: str, counters=None):
         self.name = name
-        self.extra = counters or {}
+        self.extra = {} if counters is None else counters
 
     def __enter__(self) -> Dict[str, float]:
         self.counts = _COUNTS.open()
@@ -354,6 +357,12 @@ _CURRENT_LOCK = threading.Lock()
 def get_tracer() -> Any:
     """Current tracer (a ``Tracer`` or the default ``NullTracer``)."""
     return _CURRENT
+
+
+def recording() -> bool:
+    """True while an enabled tracer is installed or a ``jax.profiler``
+    session records: the spans' counters then land somewhere."""
+    return _CURRENT.enabled or _profiling()
 
 
 def set_tracer(tracer: Any) -> Any:

@@ -62,8 +62,8 @@ def test_pair_count_sweep(m, n, tm, tn, radius):
     a, na = _full(jnp.asarray(sky.make_catalog(m, 1)))
     b, nb = _full(jnp.asarray(sky.make_catalog(n, 2)))
     cm = float(np.cos(radius))
-    got = pair_count_masked_pallas(a, b, na, nb, cm, tm=tm, tn=tn,
-                                   interpret=True)
+    got, _ = pair_count_masked_pallas(a, b, na, nb, cm, tm=tm, tn=tn,
+                                      interpret=True)
     want = pair_count_ref(a[0], b[0], cm)
     assert got.shape == (1,) and int(got[0]) == int(want)
 
@@ -73,8 +73,8 @@ def test_pair_count_exclude_self():
     count of (a, a) minus the diagonal is the exclude-self reference."""
     a, na = _full(jnp.asarray(sky.make_catalog(256, 3)))
     cm = float(np.cos(0.05))
-    got = pair_count_masked_pallas(a, a, na, na, cm, tm=128, tn=128,
-                                   interpret=True)
+    got, _ = pair_count_masked_pallas(a, a, na, na, cm, tm=128, tn=128,
+                                      interpret=True)
     want = pair_count_ref(a[0], a[0], cm, exclude_self=True)
     assert int(got[0]) - 256 == int(want)
 
@@ -84,15 +84,15 @@ def test_pair_hist_sweep(nbins):
     a, na = _full(jnp.asarray(sky.make_catalog(256, 4)))
     b, nb = _full(jnp.asarray(sky.make_catalog(512, 5)))
     edges = jnp.asarray(np.cos(np.linspace(0.01, 0.2, nbins)), jnp.float32)
-    got = pair_hist_masked_pallas(a, b, na, nb, edges, tm=256, tn=256,
-                                  interpret=True)
+    got, _ = pair_hist_masked_pallas(a, b, na, nb, edges, tm=256, tn=256,
+                                     interpret=True)
     want = pair_hist_ref(a[0], b[0], edges)
     np.testing.assert_array_equal(np.asarray(got)[0], np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
 # zones_pairs: masked-batched variants (leading partition axis + n_a/n_b
-# masking) — Pallas interpret-mode and the z-banded blocked reduce, both vs
+# masking) — Pallas interpret-mode and the blocked reduce, both vs
 # a per-partition loop over the 2D reference on the *real* (unpadded) rows.
 # ---------------------------------------------------------------------------
 
@@ -139,10 +139,10 @@ def test_pair_count_masked_ragged(P, C1, C2, n_o, n_b, radius):
     a, b, no, nb = _masked_case(P, C1, C2, n_o, n_b)
     cmin = float(np.cos(radius))
     want = _loop_count(a, b, list(n_o), list(n_b), cmin)
-    got_pl = pair_count_masked_pallas(a, b, no, nb, cmin, tm=64, tn=64,
-                                      interpret=True)
+    got_pl, _ = pair_count_masked_pallas(a, b, no, nb, cmin, tm=64, tn=64,
+                                         interpret=True)
     got_ref = pair_count_masked_ref(a, b, no, nb, cmin)
-    got_blk = pair_count_blocked(a, b, no, nb, cmin)
+    got_blk, _ = pair_count_blocked(a, b, no, nb, cmin)
     assert [int(g) for g in got_pl] == [
         _loop_count(a[p:p + 1], b[p:p + 1], n_o[p:p + 1], n_b[p:p + 1], cmin)
         for p in range(P)], got_pl
@@ -159,10 +159,10 @@ def test_pair_hist_masked_ragged(P, C1, C2, n_o, n_b, nbins):
     a, b, no, nb = _masked_case(P, C1, C2, n_o, n_b, seed=7)
     edges = jnp.asarray(np.cos(np.linspace(0.02, 0.4, nbins)), jnp.float32)
     want = _loop_hist(a, b, list(n_o), list(n_b), edges)
-    got_pl = pair_hist_masked_pallas(a, b, no, nb, edges, tm=64, tn=64,
-                                     interpret=True)
+    got_pl, _ = pair_hist_masked_pallas(a, b, no, nb, edges, tm=64, tn=64,
+                                        interpret=True)
     got_ref = pair_hist_masked_ref(a, b, no, nb, edges)
-    got_blk = pair_hist_blocked(a, b, no, nb, edges)
+    got_blk, _ = pair_hist_blocked(a, b, no, nb, edges)
     np.testing.assert_array_equal(np.asarray(got_pl, np.int64).sum(axis=0),
                                   want)
     np.testing.assert_array_equal(np.asarray(got_ref, np.int64), want)
@@ -175,11 +175,11 @@ def _masked_pallas_and_ref(kind, a, b, no, nb):
     if kind == "count":
         cmin = float(np.cos(0.3))
         return (lambda: pair_count_masked_pallas(a, b, no, nb, cmin, tm=32,
-                                                 tn=32, interpret=True).sum(),
+                                                 tn=32, interpret=True)[0].sum(),
                 pair_count_masked_ref(a, b, no, nb, cmin))
     edges = jnp.asarray(np.cos(np.linspace(0.05, 0.4, 5)), jnp.float32)
     return (lambda: pair_hist_masked_pallas(a, b, no, nb, edges, tm=32,
-                                            tn=32, interpret=True).sum(0),
+                                            tn=32, interpret=True)[0].sum(0),
             pair_hist_masked_ref(a, b, no, nb, edges))
 
 
@@ -211,23 +211,154 @@ def test_masked_pallas_traces_once_per_shape(kind, new_shape):
     assert counts["call2"]["jax_lowerings"] > 0, counts["call2"]
 
 
+def _band(n, dec_deg, ra_deg, seed, order="ra", grid=False):
+    """[n, 3] float32 unit vectors uniform over a box of declination and RA
+    (degrees; RA may run past 180), in RA order (``atan2``, as the shuffle
+    orders a zone), or in random order for ``order="random"``. ``grid``
+    rounds each coordinate to a multiple of 2^-11: every product and sum of
+    a score is then exact in float32, so a count cannot depend on the order
+    of evaluation (the CPU interpreter contracts the kernel's products and
+    sums into fused multiply-adds; the chip and the reference do not)."""
+    rng = np.random.default_rng(seed)
+    lo, hi = np.sin(np.deg2rad(dec_deg))
+    z = rng.uniform(lo, hi, n)
+    ra = np.deg2rad(rng.uniform(*ra_deg, n))
+    r = np.sqrt(1.0 - z * z)
+    x = np.stack([r * np.cos(ra), r * np.sin(ra), z], 1)
+    x = (np.round(x * 2048) / 2048 if grid else x).astype(np.float32)
+    if order == "ra":
+        return x[np.argsort(np.arctan2(x[:, 1], x[:, 0]), kind="stable")]
+    return x[rng.permutation(n)]
+
+
+ZONE = 250 / 60                     # zone height = widest edge, degrees
+
+# (dec band, RA range, n_owned per partition, n_bucket, order): owned rows
+# are the bucket's first rows' band, every partition in RA order unless
+# "random"; the sizes are cut to tm = tn = 32 and 8-tile bucket blocks, so
+# a bucket spans several blocks
+WINDOW_CASES = {
+    "ra_sorted": ((-60.8, -60.8 + ZONE), (0, 100), (700, 500), (1500, 1200),
+                  "ra"),
+    "ra_wraps_180": ((-40, -40 + ZONE), (150, 240), (600, 640), (1400, 1500),
+                     "ra"),
+    "polar": ((90 - ZONE, 90), (0, 360), (400, 300), (900, 1000), "ra"),
+    "smaller_than_a_tile": ((-50, -50 + ZONE), (0, 100), (5, 31), (20, 2),
+                            "ra"),
+    "all_padding": ((-50, -50 + ZONE), (0, 100), (0, 0), (0, 0), "ra"),
+    "unsorted": ((-60.8, -60.8 + ZONE), (0, 100), (700, 500), (1500, 1200),
+                 "random"),
+}
+
+
+def _window_case(name):
+    dec, ra, n_o, n_b, order = WINDOW_CASES[name]
+    C1, C2 = 768, 1536
+    a = np.zeros((2, C1, 3), np.float32)
+    b = np.full((2, C2, 3), 7.0, np.float32)      # padding far from all
+    for p in range(2):
+        lo = dec[0] - ZONE if p else dec[0]
+        b[p, :n_b[p]] = _band(n_b[p], (lo, dec[1]), ra, 10 * p + 1, order,
+                              grid=True)
+        a[p, :n_o[p]] = _band(n_o[p], dec, ra, 10 * p + 2, order, grid=True)
+    return (jnp.asarray(a), jnp.asarray(b), jnp.asarray(n_o, jnp.int32),
+            jnp.asarray(n_b, jnp.int32))
+
+
+@pytest.mark.parametrize("kind", ["count", "hist"])
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_windowed_kernel_is_exact(case, kind):
+    """The windowed kernel scores only the bucket tiles in each owned tile's
+    window, and its counts equal the dense masked reference bit for bit:
+    on RA-ordered zones, across RA +-180 deg, at a pole, in partitions
+    smaller than one tile, on all padding, and over unordered rows."""
+    from repro.kernels.zones_pairs.ref import (pair_count_masked_ref,
+                                               pair_hist_masked_ref)
+    a, b, no, nb = _window_case(case)
+    edges = jnp.asarray(np.cos(np.deg2rad(np.geomspace(0.2, ZONE, 6))),
+                        jnp.float32)
+    kw = dict(tm=32, tn=32, nb=8, interpret=True)
+    if kind == "count":
+        got, tiles = pair_count_masked_pallas(a, b, no, nb, edges[-1], **kw)
+        want = pair_count_masked_ref(a, b, no, nb, edges[-1])
+        assert int(np.sum(got)) == int(want)
+    else:
+        got, tiles = pair_hist_masked_pallas(a, b, no, nb, edges, **kw)
+        want = pair_hist_masked_ref(a, b, no, nb, edges)
+        np.testing.assert_array_equal(np.asarray(got).sum(0),
+                                      np.asarray(want))
+    scored, real = np.asarray(tiles).sum(0)
+    assert real == sum(-(-int(x) // 32) * -(-int(y) // 32)
+                       for x, y in zip(no, nb))
+    if case in ("ra_sorted", "ra_wraps_180"):
+        assert scored < 0.6 * real, (scored, real)
+    elif case == "polar":           # the cap is mostly within reach
+        assert 0.9 * real < scored <= real, (scored, real)
+    elif case == "unsorted":
+        assert scored == real
+    else:
+        assert scored <= real
+
+
+@pytest.mark.parametrize("order", ["ra", "random"])
+@pytest.mark.parametrize("ra", [(0, 100), (150, 240), (0, 360)])
+def test_box_test_keeps_every_tile_pair_with_a_hit(ra, order):
+    """Numpy property of the window: every tile pair holding a pair that
+    could score over the widest edge under any float32 rounding (its float64
+    score within 1e-6 of the edge) passes the box test, and every tile pair
+    that passes lies inside its owned tile's two intervals, which start and
+    end on passing tiles."""
+    from repro.kernels.zones_pairs import windows
+    tm = tn = 16
+    for seed in range(4):
+        a = _band(160, (-52, -52 + ZONE), ra, seed, order)
+        b = _band(480, (-52 - ZONE, -52 + 2 * ZONE), ra, seed + 50, order)
+        n_a, n_b = 160 - 3 * seed, 480 - 7 * seed
+        cmin = np.float32(np.cos(np.deg2rad(ZONE)))
+        dots = a.astype(np.float64) @ b.astype(np.float64).T
+        hit = (dots >= cmin - 1e-6)[:n_a, :n_b]
+        A = np.zeros((1, 160, 3), np.float32)
+        A[0, :n_a] = a[:n_a]
+        B = np.zeros((1, 480, 3), np.float32)
+        B[0, :n_b] = b[:n_b]
+        keep = np.asarray(windows.box_keep(
+            jnp.asarray(A), jnp.asarray(B), jnp.asarray([n_a]),
+            jnp.asarray([n_b]), cmin, tm, tn))[0]
+        pad = np.zeros((160, 480), bool)
+        pad[:n_a, :n_b] = hit
+        has_hit = pad.reshape(10, tm, 30, tn).any(axis=(1, 3))
+        assert not (has_hit & ~keep).any()
+        win = np.asarray(windows.two_intervals(jnp.asarray(keep)))
+        j = np.arange(30)
+        inside = (((j >= win[:, :1]) & (j < win[:, 1:2]))
+                  | ((j >= win[:, 2:3]) & (j < win[:, 3:4])))
+        assert not (keep & ~inside).any()
+        assert (win[:, 1] <= win[:, 2]).all() and (win[:, [1, 3]] >= 1).all()
+        for i in np.flatnonzero(keep.any(axis=1)):
+            lo1, hi1, lo2, hi2 = win[i]
+            ends = [lo1, hi1 - 1] + ([lo2, hi2 - 1] if hi2 > lo2 else [])
+            assert keep[i, ends].all(), (i, win[i])
+        if order == "random":
+            assert keep[:-(-n_a // tm), :-(-n_b // tn)].all()
+
+
 def test_blocked_prunes_but_counts_exactly():
-    """The z-banded blocked reduce must skip tile pairs (on a z-sorted
-    catalog spanning the sphere) yet return exactly the dense masked
-    count."""
+    """The blocked reduce must skip tile pairs (by the box test it shares
+    with the Pallas kernel, on an RA-ordered zone) yet return exactly the
+    dense masked count, and report the tile pairs it scored."""
     from repro.kernels.zones_pairs import blocked
     from repro.kernels.zones_pairs.ref import pair_count_masked_ref
-    xyz = sky.make_catalog(2048, 3)
-    xyz = xyz[np.argsort(xyz[:, 2])]        # z-sorted -> tight tile ranges
+    xyz = _band(2048, (-60, -60 + ZONE), (0, 100), 3)
     a = jnp.asarray(xyz[None])               # one big partition
     no = jnp.asarray([2048], jnp.int32)
-    cmin = float(np.cos(0.05))
+    cmin = float(np.cos(np.deg2rad(ZONE)))
     planned = blocked._plan_blocks(a, a, no, no, cmin)
     n_tiles = (2048 // blocked.TM)
-    assert len(planned[0]) < n_tiles * n_tiles          # pruning happened
-    got = blocked.pair_count_blocked(a, a, no, no, cmin)
+    assert len(planned[0]) < 0.3 * n_tiles * n_tiles      # pruning happened
+    got, tiles = blocked.pair_count_blocked(a, a, no, no, cmin)
     want = pair_count_masked_ref(a, a, no, no, cmin)
     assert int(got) == int(want)
+    assert np.asarray(tiles).tolist() == [len(planned[0]), n_tiles ** 2]
 
 
 # ---------------------------------------------------------------------------

@@ -218,6 +218,97 @@ def test_engine_parity_jnp_index_path():
         assert getattr(got.stats, f) == getattr(want.stats, f), f
 
 
+ZONE_RAD = np.deg2rad(250 / 60)      # a Y1 zone: 250 arcmin
+
+
+def _y1_like(n, seed, order="random"):
+    """A DES Y1-like tile: ``n`` rows uniform over dec -60.83..-40 deg (5
+    zones of 250 arcmin) and RA 0..100 deg, in arrival order ``order``
+    ("random", or "ra")."""
+    rng = np.random.default_rng(seed)
+    z = rng.uniform(np.sin(np.deg2rad(-60.83)), np.sin(np.deg2rad(-40.0)), n)
+    ra = np.deg2rad(rng.uniform(0.0, 100.0, n))
+    r = np.sqrt(1.0 - z * z)
+    xyz = np.stack([r * np.cos(ra), r * np.sin(ra), z], 1).astype(np.float32)
+    return xyz[np.argsort(ra)] if order == "ra" else xyz
+
+
+def _partition_rows(cat):
+    """-> per real partition of a ``ResidentCatalog``: (owned, bucket) rows
+    in the order the shuffle left them."""
+    out = []
+    for t in cat.sd.tiers:
+        own = np.asarray(cat.codec.decode_device(*t.owned_wire))
+        bkt = np.asarray(cat.codec.decode_device(*t.bucket_wire))
+        no, nb = np.asarray(t.n_owned), np.asarray(t.n_bucket)
+        out += [(own[p, :no[p]], bkt[p, :nb[p]]) for p in range(len(t.part_ids))]
+    return out
+
+
+def test_shuffle_orders_each_zone_by_ra():
+    """Both index paths lay every partition's owned rows and bucket entries
+    out in RA order (the jnp path to within one quantum of its 25-bit key),
+    with the same tier shapes, and both give the same answers."""
+    from repro.mapreduce import job as job_mod
+    from repro.mapreduce.job import shuffle_once
+    xyz = _y1_like(3000, 4)
+    part = ZonePartitioner(ZONE_RAD, ZONE_RAD)
+    hjob = neighbor_statistics_job(np.geomspace(10, 250, 5) * 60, tile=64,
+                                   partitioner=part)
+    ra = np.arctan2(xyz[:, 1], xyz[:, 0])
+    quantum = 2 * (ra.max() - ra.min()) / 2 ** 25
+    got = {}
+    old = job_mod.SHUFFLE_INDEX_IMPL
+    try:
+        for impl in ("host", "jnp"):
+            job_mod.SHUFFLE_INDEX_IMPL = impl
+            cat = shuffle_once(part, xyz, tile=64)
+            for own, bkt in _partition_rows(cat):
+                for rows in (own, bkt):
+                    step = np.diff(np.arctan2(rows[:, 1], rows[:, 0]))
+                    assert (step >= (-quantum if impl == "jnp" else 0)).all()
+            got[impl] = ([(t.Pt, t.C1, t.C2) for t in cat.sd.tiers],
+                         cat.run(hjob)[0].output)
+    finally:
+        job_mod.SHUFFLE_INDEX_IMPL = old
+    assert got["host"][0] == got["jnp"][0]
+    np.testing.assert_array_equal(got["host"][1], got["jnp"][1])
+
+
+def test_pair_tile_counter_reads_the_window_share():
+    """While a tracer records, the reduce reports the tile pairs its kernel
+    scored against those of real rows, on ``StageStats`` and on the
+    ``reduce`` span: on an RA-ordered Y1-like tile the windows keep at most
+    three in ten; on rows left in arrival order (a partitioner with no sort
+    key, rows shuffled) every tile pair is scored. With nothing recording,
+    nothing is read."""
+    import dataclasses
+
+    from repro.obs import Tracer, use_tracer
+
+    @dataclasses.dataclass(frozen=True)
+    class ArrivalOrder(ZonePartitioner):
+        def sort_key_device(self, items):
+            return None
+
+    xyz = _y1_like(12000, 5)
+    edges = np.geomspace(10, 250, 4) * 60
+    ratio = {}
+    for name, part in (("ra", ZonePartitioner(ZONE_RAD, ZONE_RAD)),
+                       ("arrival", ArrivalOrder(ZONE_RAD, ZONE_RAD))):
+        job = neighbor_statistics_job(edges, tile=256, partitioner=part)
+        with use_tracer(Tracer()) as tr:
+            st = run_job(job, xyz, engine="device").stats
+        span = next(e["args"] for e in tr.events if e["name"] == "reduce")
+        assert span["pair_tiles_scored"] == st.pair_tiles_scored
+        assert span["pair_tiles_real"] == st.pair_tiles_real > 0
+        ratio[name] = st.pair_tiles_scored / st.pair_tiles_real
+        quiet = run_job(job, xyz, engine="device").stats
+        assert quiet.pair_tiles_scored == quiet.pair_tiles_real == 0
+    assert ratio["ra"] <= 0.3, ratio
+    assert ratio["arrival"] == 1.0, ratio
+
+
 def test_device_engine_stats_and_wire_accounting():
     xyz = sky.make_catalog(800, 6)
     res = run_job(neighbor_search_job(0.06, codec="int16", tile=64), xyz,

@@ -204,6 +204,7 @@ def test_null_tracer_is_reentrant_noop():
 DEVICE_SPANS = {"mr:job", "mr:map", "mr:shuffle", "mr:shuffle.wait",
                 "mr:shuffle.plan", "mr:shuffle.scatter", "mr:shuffle.tiers",
                 "mr:reduce", "mr:reduce.dispatch", "mr:reduce.wait"}
+TILE_COUNTERS = {"pair_tiles_scored", "pair_tiles_real"}
 
 
 def _profiled_spans(trace_dir):
@@ -226,7 +227,8 @@ def _profiled_spans(trace_dir):
 def test_profiler_session_records_device_job_spans(tmp_path):
     """Under a ``jax.profiler`` session and the default ``NullTracer``, a
     device-engine job writes every seam's span into the ``.xplane.pb``,
-    nested inside ``mr:job`` and carrying the compile counters."""
+    nested inside ``mr:job`` and carrying the compile counters, and
+    ``mr:reduce`` the pair kernel's tile pairs."""
     import jax
     xyz = _catalog(2345, seed=4)     # a catalog size of its own: it compiles
     job = neighbor_search_job(RADIUS, tile=128)
@@ -239,8 +241,11 @@ def test_profiler_session_records_device_job_spans(tmp_path):
     for name, evs in spans.items():
         for s, e, stats in evs:
             assert j0 <= s <= e <= j1, name
-            assert set(stats) == set(trace_mod.COUNTERS), name
+            own = TILE_COUNTERS if name == "mr:reduce" else set()
+            assert set(stats) == set(trace_mod.COUNTERS) | own, name
             assert stats["jax_compile_s"] <= job_stats["jax_compile_s"]
+    ((_, _, red),) = spans["mr:reduce"]
+    assert 0 < red["pair_tiles_scored"] <= red["pair_tiles_real"]
     for parent in ("mr:shuffle", "mr:reduce"):
         ((p0, p1, _),) = spans[parent]
         for name in DEVICE_SPANS:

@@ -3,9 +3,10 @@
 Interpret-mode tests cannot see what the Pallas TPU lowering refuses
 (unaligned blocks, rank-1 VMEM blocks, VMEM overruns) or whether a sharded
 reduce keeps its kernel and all-reduce. These compile the masked pair
-kernels at engine shapes (``tile=256``, capacities up to 8192) and one
-4-device sharded count + ``psum`` for a described ``v5e:2x2``. Nothing
-runs, so they say nothing about results or times.
+kernels at engine shapes (``tile=256``, capacities up to 8192, and the
+benchmark cells' tiers) and one 4-device sharded count + ``psum`` for a
+described ``v5e:2x2``. Nothing runs, so they say nothing about results or
+times.
 """
 import re
 
@@ -65,26 +66,53 @@ def test_masked_kernels_compile_for_v5e(topo, no_persistent_cache, P_, C1,
     if nbins:
         e = jax.ShapeDtypeStruct((nbins,), jnp.float32, sharding=one)
         compiled, _ = _compile(pair_hist_masked_pallas, a, b, n, n, e)
-        assert compiled.out_info.shape == (P_, nbins)
+        assert compiled.out_info[0].shape == (P_, nbins)
     else:
         compiled, _ = _compile(
             lambda a, b, na, nb: pair_count_masked_pallas(
                 a, b, na, nb, float(np.cos(0.02))), a, b, n, n)
-        assert compiled.out_info.shape == (P_,)
+        assert compiled.out_info[0].shape == (P_,)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4 << 30
+
+
+@pytest.mark.parametrize("P_,C1,C2", [
+    (2, 56320, 108800),         # DES Y1 z3 (240k rows): the two big tiers
+    (3, 52480, 157184),
+    (1, 137216, 286464),        # DES Y3 z3 on four chips: one chip's share
+    (1, 130304, 389888),
+    (9, 256, 137216),           # ... and of the tier of empty zones
+])
+def test_windowed_kernel_compiles_at_cell_tiers(topo, no_persistent_cache,
+                                                P_, C1, C2):
+    """The windowed histogram at the benchmark cells' tier shapes (16
+    edges): Mosaic refuses a kernel whose VMEM blocks overrun the scoped
+    limit, so a compile at the largest capacities is the check that VMEM
+    does not grow with them; the window tables fit SMEM, and the windows'
+    and bucket layout's temporaries stay small."""
+    one = SingleDeviceSharding(topo.devices[0])
+    a = jax.ShapeDtypeStruct((P_, C1, 3), jnp.float32, sharding=one)
+    b = jax.ShapeDtypeStruct((P_, C2, 3), jnp.float32, sharding=one)
+    n = jax.ShapeDtypeStruct((P_,), jnp.int32, sharding=one)
+    e = jax.ShapeDtypeStruct((16,), jnp.float32, sharding=one)
+    compiled, text = _compile(pair_hist_masked_pallas, a, b, n, n, e)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.out_info[0].shape == (P_, 16)
+    assert compiled.out_info[1].shape == (P_, 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def test_pair_kernel_keeps_its_profile_name(topo, no_persistent_cache):
     """A device profile finds the pair kernel by the name of its custom
     call: the program each tier dispatches names it ``tpu_custom_call``."""
     one = SingleDeviceSharding(topo.devices[0])
-    P_, C1, C2, nbins = 4, 1024, 2048, 16
+    P_, C1, G, nbins = 4, 1024, 8, 16
     n = jax.ShapeDtypeStruct((P_,), jnp.int32, sharding=one)
-    text = _hist_call(P_, C1, C2, 3, nbins, 256, 256, False).lower(
+    text = _hist_call(P_, C1, G, 3, nbins, 256, 256, 8, False).lower(
+        jax.ShapeDtypeStruct((P_ * C1 // 256 * 4,), jnp.int32, sharding=one),
         n, n, jax.ShapeDtypeStruct((nbins,), jnp.float32, sharding=one),
         jax.ShapeDtypeStruct((P_, C1, 3), jnp.float32, sharding=one),
-        jax.ShapeDtypeStruct((P_, 3, C2), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((P_, G, 3, 256), jnp.float32, sharding=one),
     ).compile().as_text()
     assert re.search(r"%tpu_custom_call[.\d]* = [^\n]*custom-call", text)
 
@@ -97,7 +125,7 @@ def test_sharded_count_psum_compiles_for_v5e_2x2(topo, no_persistent_cache):
 
     def body(a, b, na, nb):
         return jax.lax.psum(
-            jnp.sum(pair_count_masked_pallas(a, b, na, nb, cmin)), "data")
+            jnp.sum(pair_count_masked_pallas(a, b, na, nb, cmin)[0]), "data")
 
     fn = shard_map(body, mesh=mesh, in_specs=(P("data"),) * 4,
                    out_specs=P(), axis_names=frozenset({"data"}))
