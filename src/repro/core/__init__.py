@@ -16,7 +16,3 @@ from repro.core.compression import (
 from repro.core.hlo_analysis import (
     parse_collectives, collective_summary, op_census,
 )
-from repro.core.cost_model import (
-    BackendProfile, CostModel, StageCost, backend_fingerprint,
-    calibration_enabled, get_cost_model, reset_cost_model, stage_census,
-)

@@ -1,6 +1,6 @@
 """Where entry points keep JAX's persistent compilation cache.
 
-Called by entry points (``chip_smoke.py``, ``benchmarks/run.py``,
+Called by entry points (``chip_smoke.py``, ``bench/run.py``,
 ``launch/serve_mr.py``, the examples), never at library import. Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing else
 is set. Otherwise the cache goes to a fixed ``<checkout>/.jax_cache``: the

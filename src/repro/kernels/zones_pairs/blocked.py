@@ -20,13 +20,10 @@ Chunk geometry: TM=TN=64 rows (falls back to the largest divisor of the
 capacity), B0=512 blocks per chunk — ~2M score cells per dispatch, enough
 to amortize dispatch overhead while keeping the [B0, TM, TN] score tensor
 inside the L2-ish working set. ``chunk_shape()`` resolves the shape per
-run: ``set_chunk_shape`` override > ``REPRO_AUTO_CHUNK=1`` (the cost
-model's calibrated replay-measured choice) > these constants. Results are
+run: the ``set_chunk_shape`` override, else these constants. Results are
 bit-identical for every shape.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -41,10 +38,9 @@ TN = 64           # tile rows (bucket side)
 B0 = 512          # blocks per kernel dispatch (fixed -> one compile)
 
 # chunk-shape resolution: hand-tuned module constants by default; an explicit
-# override (tests / power users) wins; REPRO_AUTO_CHUNK=1 asks the cost
-# model, which only deviates from the hand-tuned shape when its calibration
-# replay measured a faster one. Any shape is exact — tiles are masked and
-# ``_fit_tile`` handles every capacity — so this changes speed, never bits.
+# override (tests / power users) wins. Any shape is exact — tiles are masked
+# and ``_fit_tile`` handles every capacity — so this changes speed, never
+# bits.
 _CHUNK_OVERRIDE: "tuple[int, int, int] | None" = None
 
 
@@ -59,12 +55,7 @@ def set_chunk_shape(tm: int | None = None, tn: int | None = None,
 
 def chunk_shape() -> "tuple[int, int, int]":
     """The (TM, TN, B0) the blocked engine will use for the next run."""
-    if _CHUNK_OVERRIDE is not None:
-        return _CHUNK_OVERRIDE
-    if os.environ.get("REPRO_AUTO_CHUNK") == "1":
-        from repro.core.cost_model import get_cost_model
-        return get_cost_model().choose_blocked_chunk(default=(TM, TN, B0))
-    return (TM, TN, B0)
+    return _CHUNK_OVERRIDE if _CHUNK_OVERRIDE is not None else (TM, TN, B0)
 
 
 @jax.jit

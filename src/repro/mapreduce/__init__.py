@@ -36,8 +36,8 @@ from repro.mapreduce.job import (DeviceShuffledData, HashPartitioner,
                                  ShuffledData, StreamSummary, TierData,
                                  concat_mapped, group_batch_compatible,
                                  map_split_device, plan_tiers, reduce_stage,
-                                 resolve_auto_job, run_job, run_jobs,
-                                 shuffle_once, shuffle_reduce_device,
+                                 run_job, run_jobs, shuffle_once,
+                                 shuffle_reduce_device,
                                  shuffle_reduce_device_streamed,
                                  shuffle_signature, shuffle_stage)
 from repro.mapreduce.executor import (Combiner, JobDeadlineExceeded,

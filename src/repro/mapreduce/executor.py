@@ -77,7 +77,7 @@ from repro.mapreduce.job import (JobResult, MappedSplit,  # noqa: F401
                                  StreamSummary, concat_mapped,
                                  concat_streams, host_shuffle_reduce,
                                  map_split_device, map_stream,
-                                 resolve_auto_job, shuffle_reduce_device,
+                                 shuffle_reduce_device,
                                  shuffle_reduce_device_streamed,
                                  validate_batch)
 from repro.mapreduce.spill import (SpillConfig, SpillStore, mapped_to_host,
@@ -228,15 +228,8 @@ class _ResidentMeter:
 
 def _auto_ranges(cfg: SpillConfig, est_total_bytes: float, P: int) -> int:
     """Read-back range count: ~4 ranges per budget's worth of estimated
-    spill, so one range's resident bytes sit well inside the budget.
-    ``n_ranges="auto"`` consults the cost model instead (fewest ranges whose
-    per-range read-back fits the flush watermark — fewer replans, each with
-    fixed dispatch overhead); an int forces it; None keeps the heuristic."""
-    if cfg.n_ranges == "auto":
-        from repro.core.cost_model import get_cost_model
-        return get_cost_model().choose_spill_ranges(
-            float(est_total_bytes), float(cfg.budget_bytes), int(P),
-            int(cfg.max_ranges))
+    spill, so one range's resident bytes sit well inside the budget; an int
+    ``n_ranges`` forces it."""
     if cfg.n_ranges is not None:
         z = int(cfg.n_ranges)
     else:
@@ -848,10 +841,6 @@ def run_jobs_streaming(jobs, source: SplitSource, *, mesh=None,
     """
     if not jobs:
         return []
-    # codec="auto" materializes here, BEFORE signature validation — every
-    # downstream get_codec/shuffle_signature sees a concrete codec. The
-    # cost model only picks among exact codecs, so results cannot change.
-    jobs = [resolve_auto_job(j) for j in jobs]
     validate_batch(jobs)
     if engine == "auto":
         engine = "device"

@@ -95,12 +95,6 @@ class StageStats:
     clone_wins: int = 0                # splits where the clone finished first
     retries: int = 0                   # transient-fault re-dispatches
     lane_walls: tuple = ()             # per-lane busy seconds, length n_lanes
-    # cost-model accounting (core/cost_model.py): the predicted stage walls
-    # recorded alongside the measured ones, so model error is observable in
-    # every bench row, and the tile the model resolved when tile="auto"
-    predicted_shuffle_wall_s: float = 0.0
-    predicted_reduce_wall_s: float = 0.0
-    auto_tile: int = 0                 # 0 = tile was not auto-planned
     # energy accounting (obs/energy.py): joules per stage, measured (RAPL/
     # NVML counter deltas spread by active-wall share) or modeled
     # (PowerProfile watts x stage wall). All zero when metering is off.
@@ -123,7 +117,6 @@ class StageStats:
                      "fetch_wall_s", "combine_wall_s", "overlap_hidden_s",
                      "spill_bytes", "spill_wall_s", "spilled_splits",
                      "speculated", "clone_wins", "retries",
-                     "predicted_shuffle_wall_s", "predicted_reduce_wall_s",
                      "energy_j", "map_energy_j", "shuffle_energy_j",
                      "reduce_energy_j", "fetch_energy_j", "combine_energy_j",
                      "spill_energy_j")
@@ -137,22 +130,11 @@ class StageStats:
         for f in self._ACCUM_FIELDS:
             setattr(self, f, getattr(self, f) + getattr(other, f))
         for f in ("n_partitions", "n_shards", "shuffle_index_impl",
-                  "auto_tile", "energy_source"):
+                  "energy_source"):
             mine = getattr(self, f)
             if mine in (0, 1, ""):
                 setattr(self, f, getattr(other, f))
         return self
-
-    @property
-    def prediction_error(self) -> float:
-        """Worst predicted-vs-actual stage-wall ratio, folded to >= 1.0
-        (a 2.0 means the cost model was off by 2x in either direction on
-        some stage); 0.0 when no prediction was recorded."""
-        errs = [max(p / a, a / p) for p, a in
-                ((self.predicted_shuffle_wall_s, self.shuffle_wall_s),
-                 (self.predicted_reduce_wall_s, self.reduce_wall_s))
-                if p > 0.0 and a > 0.0]
-        return max(errs) if errs else 0.0
 
     @property
     def wall_s(self) -> float:
@@ -215,7 +197,6 @@ class StageStats:
         d.update(wall_s=self.wall_s, dominant_stage=self.dominant_stage,
                  compression_ratio=self.compression_ratio,
                  overlap_fraction=self.overlap_fraction,
-                 prediction_error=self.prediction_error,
                  rows_per_joule=self.rows_per_joule)
         d["amdahl"] = self.roofline(chips).to_dict()
         return d

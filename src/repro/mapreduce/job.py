@@ -168,13 +168,6 @@ class Reducer:
 
     pad_value: float = 0.0   # fill for capacity padding; pick one kernels ignore
 
-    # cost-model basis for tile="auto" planning (class attr, not a field):
-    # "pairs" = work quadratic in score cells (cross-row reducers);
-    # "rows"  = work linear in owned rows (monoid/bincount reducers), where
-    # extra tiers are mostly fixed overhead. Never affects results — only
-    # which tile/tier split the planner predicts fastest.
-    cost_basis = "pairs"
-
     def per_partition(self, owned_p, bucket_p):
         """[C1, d], [C2, d] -> fixed-shape array, summed over partitions."""
         raise NotImplementedError
@@ -252,8 +245,7 @@ class _PaddingAccounting:
     @property
     def padded_ratio(self) -> float:
         """pair_cells / real_pair_cells — how much compute the capacity
-        padding inflates (the fig3 ``bigger_blocks`` inversion in one
-        number)."""
+        padding inflates (the bigger-blocks inversion in one number)."""
         real = self.real_pair_cells
         return self.pair_cells / real if real else 1.0
 
@@ -322,30 +314,27 @@ class DeviceShuffledData(_PaddingAccounting):
 
 @dataclasses.dataclass
 class MapReduceJob:
-    """A named composition of the three pluggable stages.
-
-    ``codec="auto"`` / ``tile="auto"`` delegate the choice to the cost
-    model (``core/cost_model.py``): codec resolves at job entry (exact
-    codecs only, so arithmetic never changes), tile at shuffle time when
-    the per-partition counts are known. Both default to the historical
-    concrete values — auto is opt-in."""
+    """A named composition of the three pluggable stages. ``codec`` names a
+    registered codec (or is one); ``tile`` is a positive int, checked here
+    so every entry point refuses a bad one before any work runs."""
 
     name: str
     partitioner: Partitioner
     reducer: Reducer
     codec: str | ShuffleCodec = "identity"
-    tile: int | str = 256      # capacity quantum (the paper's block size)
+    tile: int = 256            # capacity quantum (the paper's block size)
+
+    def __post_init__(self):
+        check_positive_int("tile", self.tile, 256)
 
 
-def resolve_auto_job(job: MapReduceJob) -> MapReduceJob:
-    """Materialize ``codec="auto"`` via the cost model. Exact codecs only —
-    auto choices change shapes, never arithmetic. ``tile="auto"`` stays on
-    the job: it resolves inside ``_shuffle_mapped`` where the per-partition
-    counts exist."""
-    if job.codec == "auto":
-        from repro.core.cost_model import get_cost_model
-        job = dataclasses.replace(job, codec=get_cost_model().choose_codec())
-    return job
+def check_positive_int(name: str, value, example: int) -> None:
+    """Refuse a setting that is not a positive int (a stale ``"auto"``
+    included) before any work runs, naming the concrete form."""
+    if (isinstance(value, bool) or not isinstance(value, (int, np.integer))
+            or value < 1):
+        raise ValueError(f"{name} must be a positive int such as "
+                         f"{name}={example}, got {value!r}")
 
 
 @dataclasses.dataclass
@@ -371,16 +360,7 @@ def shuffle_stage(items, partitioner: Partitioner, codec="identity", *,
     copy is ever materialized just for accounting. Wire bytes count every
     point that lands in a bucket (owned + border copies), matching the
     paper's "bytes that crossed the shuffle" accounting.
-
-    ``codec="auto"`` resolves through the cost model; ``tile="auto"`` takes
-    the historical host default (the host engine's results are tile-
-    independent — padding is masked — so there is nothing to plan).
     """
-    if codec == "auto":
-        from repro.core.cost_model import get_cost_model
-        codec = get_cost_model().choose_codec()
-    if tile == "auto":
-        tile = 256
     codec = get_codec(codec)
     items = np.asarray(items)
     if items.ndim == 1:
@@ -469,21 +449,17 @@ def reduce_stage(reducers, sd: ShuffledData, mesh=None):
 # ---------------------------------------------------------------------------
 
 def plan_tiers(n_owned, n_bucket, tile: int, max_tiers: int = 3,
-               pad_partitions_to: int = 1, tier_cost=None):
+               pad_partitions_to: int = 1):
     """Group partitions into <= ``max_tiers`` capacity size classes.
 
     One global capacity (the host engine's choice) is sized by the most
     skewed partition, so every partition pays the worst partition's padding
-    — the fig3 ``bigger_blocks`` inversion. Tiers bound that: partitions are
+    — the bigger-blocks inversion. Tiers bound that: partitions are
     grouped by bucket capacity (rounded to the ``tile`` quantum) and each
     tier is padded only to ITS max. The <=2 split points are chosen by
-    exact search over distinct capacities, minimizing total tier cost.
-
-    ``tier_cost``: optional vectorized callable ``f(Pt, C1, C2) -> cost``
-    over float64 numpy arrays (``Pt`` = phantom-padded partition count) —
-    e.g. the cost model's predicted tier wall
-    (``CostModel.tier_cost_fn()``). Default: padded pair cells
-    ``Pt * C1 * C2``, bit-identical to the historical planner.
+    exact search over distinct capacities, minimizing total tier cost: the
+    padded pair cells ``Pt * C1 * C2`` (``Pt`` = phantom-padded partition
+    count).
 
     ``pad_partitions_to`` (the mesh's ``data`` axis size): each tier's
     partition count is rounded up to a multiple of it with phantom
@@ -533,11 +509,7 @@ def plan_tiers(n_owned, n_bucket, tile: int, max_tiers: int = 3,
     Pt = np.maximum(pad, -(-cnt // pad) * pad).astype(np.float64)
     C1 = np.maximum(tile, -(-seg_max // tile) * tile).astype(np.float64)
     C2 = np.broadcast_to(uniq.astype(np.float64)[None, :], (U, U))
-    if tier_cost is None:
-        S = Pt * C1 * C2
-    else:
-        S = np.asarray(tier_cost(Pt, C1, C2), np.float64)
-    S = np.where(col >= row, S, np.inf)
+    S = np.where(col >= row, Pt * C1 * C2, np.inf)
 
     best_cost = float(S[0, U - 1])
     best_cuts = (U - 1,)
@@ -1068,7 +1040,6 @@ class ResidentCatalog:
     n_rows: int = 0
     d: int = 0
     load_stats: StageStats = None      # the shuffle-once cost (set by shuffle_once)
-    tile_resolved: int = 0             # concrete tile when ``tile == "auto"``
 
     @property
     def nbytes(self) -> int:
@@ -1131,17 +1102,7 @@ class ResidentCatalog:
         stats.pair_tiles_real += tiles.get("pair_tiles_real", 0)
         stats.reduce_wall_s += t1 - t0
         stats.reduce_bytes += self.nbytes
-        flops = float(sum(r.flops(self.sd) for r in reducers))
-        stats.reduce_flops += flops
-        # predicted reduce wall from the same accounting the stats carry:
-        # reducer flops + decoded score cells and resident wire traffic
-        from repro.core.cost_model import StageCost, get_cost_model
-        cells = self.sd.pair_cells
-        stats.predicted_reduce_wall_s += get_cost_model().predict_wall(
-            StageCost(flops=flops,
-                      hbm_bytes=4.0 * cells * len(reducers) + self.nbytes,
-                      n_dispatch=max(cells / (64.0 * 64.0 * 512.0), 1.0)
-                      * len(self.sd.tiers)))
+        stats.reduce_flops += float(sum(r.flops(self.sd) for r in reducers))
         return totals
 
     def run(self, jobs, stats: StageStats = None) -> "list[JobResult]":
@@ -1181,10 +1142,9 @@ def _tile_pairs(reducers, totals) -> dict:
     return {"pair_tiles_scored": int(scored), "pair_tiles_real": int(real)}
 
 
-def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
+def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile: int,
                     pad_value: float, m, P: int,
-                    stats: StageStats, mesh=None,
-                    cost_basis: str = "pairs") -> ResidentCatalog:
+                    stats: StageStats, mesh=None) -> ResidentCatalog:
     """Shuffle one mapped stream into device-resident tiers: count, tier,
     argsort-bucket, scatter in wire dtype — the shuffle half of
     ``shuffle_reduce_device``, accumulating (``+=``) into ``stats``.
@@ -1195,14 +1155,7 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
     to its owner, so the tiers are born sharded over ``data``. Tier
     partition counts are padded to a multiple of D with phantom
     (zero-count) partitions, so every tier splits evenly across shards.
-
-    ``tile="auto"`` asks the cost model for the tile quantum AND the tier
-    split minimizing the predicted reduce wall (instead of padded-cell
-    count); the resolved tile lands in ``stats.auto_tile`` and
-    ``ResidentCatalog.tile_resolved``. Either way the predicted shuffle
-    wall is recorded so model error is observable per stage.
     -> ResidentCatalog."""
-    from repro.core.cost_model import StageCost, get_cost_model
     D = _data_axis_size(mesh)
     if D > 1:
         blocks = list(m) if isinstance(m, list) else [m]
@@ -1236,14 +1189,7 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
                     np.int64)
                 n_bucket = np.bincount(dest_h, minlength=P + 1)[:P].astype(
                     np.int64)
-            tile_req = tile
-            if tile == "auto":
-                tile, plan, _ = get_cost_model().plan_shuffle(
-                    n_owned, n_bucket, D, d=d, basis=cost_basis)
-                stats.auto_tile = int(tile)
-            else:
-                plan = plan_tiers(n_owned, n_bucket, tile,
-                                  pad_partitions_to=D)
+            plan = plan_tiers(n_owned, n_bucket, tile, pad_partitions_to=D)
             specs = tuple((_round_up(len(ids), D), C1, C2)
                           for ids, C1, C2 in plan)
             if D > 1:
@@ -1313,20 +1259,14 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile,
     stats.shuffle_wall_s += t1 - t0
     stats.shuffle_wire_bytes += wire
     stats.shuffle_raw_bytes += 4 * n_shuffled * d
-    # predicted shuffle wall: the sort/scatter is byte-bound — payload rows
-    # make ~3 passes and the index stream ~16B per shuffled row
-    stats.predicted_shuffle_wall_s += get_cost_model().predict_wall(
-        StageCost(flops=0.0, hbm_bytes=3.0 * wire + 16.0 * n_shuffled,
-                  n_dispatch=len(plan) + 2))
     stats.n_items += n_rows
     stats.n_partitions = P
     stats.codec = codec.name
     stats.engine = "device"
     stats.n_shards = D
-    return ResidentCatalog(partitioner, codec, tile_req, pad_value, sd, P,
+    return ResidentCatalog(partitioner, codec, tile, pad_value, sd, P,
                            mesh=mesh, shard_pad=shard_pad,
-                           shard_real=shard_real, n_rows=n_rows, d=d,
-                           tile_resolved=int(tile))
+                           shard_real=shard_real, n_rows=n_rows, d=d)
 
 
 def _blocked_stream(blocks, mesh, P: int) -> MappedSplit:
@@ -1361,7 +1301,7 @@ def _put_counts(x: np.ndarray, mesh):
 
 
 def shuffle_once(partitioner: Partitioner, items, *, codec="identity",
-                 tile: int | str = 256, pad_value: float = 0.0, mesh=None,
+                 tile: int = 256, pad_value: float = 0.0, mesh=None,
                  stats: StageStats = None) -> ResidentCatalog:
     """Load + map + shuffle a catalog ONCE into device-resident tiered
     wire-dtype partitions. The returned handle's ``run(jobs)`` serves any
@@ -1369,9 +1309,7 @@ def shuffle_once(partitioner: Partitioner, items, *, codec="identity",
     shuffle-then-reduce decomposition that ``run_jobs`` executes per call
     and the MR query service amortizes across requests. The shuffle cost
     lands in ``stats`` (also kept as ``ResidentCatalog.load_stats``)."""
-    if codec == "auto":
-        from repro.core.cost_model import get_cost_model
-        codec = get_cost_model().choose_codec()
+    check_positive_int("tile", tile, 256)
     codec = get_codec(codec)
     if stats is None:
         stats = StageStats(job="shuffle_once")
@@ -1415,9 +1353,7 @@ def shuffle_reduce_device(jobs, m: MappedSplit, P: int, stats: StageStats,
     """
     j0 = jobs[0]
     cat = _shuffle_mapped(j0.partitioner, get_codec(j0.codec), j0.tile,
-                          j0.reducer.pad_value, m, P, stats, mesh,
-                          cost_basis=getattr(j0.reducer, "cost_basis",
-                                             "pairs"))
+                          j0.reducer.pad_value, m, P, stats, mesh)
     totals = cat.reduce_totals(tuple(j.reducer for j in jobs), stats)
     return totals, cat.sd, cat.shard_pad, cat.shard_real
 
@@ -1599,17 +1535,14 @@ def run_jobs(jobs, items, *, mesh=None, engine: str = "auto",
     mesh). -> one JobResult per job, sharing a single StageStats.
 
     ``split_rows``: ``None`` (default) runs the whole catalog as one split;
-    an int streams it in row chunks of that size; ``"auto"`` asks the cost
-    model for a chunk size that amortizes per-split dispatch overhead while
-    bounding the working set. Streaming is bit-identical to monolithic for
-    exact codecs, so this only changes shapes, never results."""
+    a positive int streams it in row chunks of that size. Streaming is
+    bit-identical to monolithic for exact codecs, so this only changes
+    shapes, never results."""
     from repro.data.pipeline import ArraySplits
     from repro.mapreduce.executor import run_jobs_streaming
+    if split_rows is not None:
+        check_positive_int("split_rows", split_rows, 4096)
     rows = np.asarray(items)
-    if split_rows == "auto":
-        from repro.core.cost_model import get_cost_model
-        d = rows.shape[1] if rows.ndim > 1 else 1
-        split_rows = get_cost_model().choose_split_rows(len(rows), d=d)
     n_splits = (1 if split_rows is None
                 else max(1, -(-len(rows) // int(split_rows))))
     return run_jobs_streaming(jobs, ArraySplits(items, n_splits=n_splits),

@@ -59,7 +59,7 @@ import time
 import numpy as np
 
 from repro.data.pipeline import Prefetcher
-from repro.mapreduce.job import MappedSplit
+from repro.mapreduce.job import MappedSplit, check_positive_int
 from repro.obs.trace import get_tracer
 
 _MAGIC = b"SPL1"
@@ -74,15 +74,18 @@ class SpillConfig:
     ``0`` spills every split. ``dir``: spill root (a fresh temp dir when
     None; always reclaimed on close). ``n_ranges``: read-back partition
     range count (None = sized so a range's wire bytes fit well inside the
-    budget, capped at ``max_ranges``; ``"auto"`` = the cost model picks the
-    fewest ranges whose read-back fits the flush watermark). ``write_fault``:
+    budget, capped at ``max_ranges``; else a positive int). ``write_fault``:
     chaos hook ``f(path)`` invoked mid-segment-write (fault injection)."""
 
     budget_bytes: float | None = None
     dir: str | None = None
-    n_ranges: int | str | None = None
+    n_ranges: int | None = None
     max_ranges: int = 256
     write_fault: object = None
+
+    def __post_init__(self):
+        if self.n_ranges is not None:
+            check_positive_int("n_ranges", self.n_ranges, 8)
 
     @property
     def enabled(self) -> bool:

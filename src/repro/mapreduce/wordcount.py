@@ -67,7 +67,6 @@ class TokenHistogramReducer(Reducer):
 
     vocab: int
     pad_value: float = -1.0
-    cost_basis = "rows"   # bincount is linear in owned rows, not pair cells
 
     @staticmethod
     def _weights(owned, valid):

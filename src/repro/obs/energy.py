@@ -12,8 +12,8 @@ per-stage joules on its ``StageStats``:
 - ``NvmlMeter``: NVIDIA total-energy counter via pynvml, when importable
   and a device is present.
 - ``ModeledMeter``: watts x wall from a ``PowerProfile`` — the fallback
-  that always works, and the one ``fig9_energy`` uses so the efficiency
-  ratios are reproducible on any machine.
+  that always works, so the efficiency ratios are reproducible on any
+  machine.
 
 Measured meters (RAPL/NVML) observe one counter delta per run and
 attribute it to stages by active-wall share; the modeled meter charges

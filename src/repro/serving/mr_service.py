@@ -22,11 +22,11 @@ one-shot batch jobs):
   caches in ``mapreduce/job.py`` key on (reducers, codec, mesh), so a
   recurring query mix stops retracing after its first batch;
 - every request carries a ``RequestStats`` (queue wait, batch wall, latency);
-  ``latency_summary`` turns the stream into qps/p50/p99 rows (the
-  ``fig5_service`` benchmark), and per-batch walls feed an optional
-  ``straggler_monitor=`` hook with the same ``record(index, wall_s)``
-  contract as the streaming executor — ``ft.SpeculativePolicy`` spots slow
-  batches in serving mode exactly as it spots slow splits in batch mode.
+  ``latency_summary`` turns the stream into qps/p50/p99 rows, and per-batch
+  walls feed an optional ``straggler_monitor=`` hook with the same
+  ``record(index, wall_s)`` contract as the streaming executor —
+  ``ft.SpeculativePolicy`` spots slow batches in serving mode exactly as it
+  spots slow splits in batch mode.
 
     svc = MRQueryService(max_batch=16, max_wait_s=0.002)
     svc.load_catalog("sky", xyz, ZonePartitioner(0.02), codec="int16")

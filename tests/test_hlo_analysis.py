@@ -81,28 +81,37 @@ def test_shape_bytes():
 
 
 # ---------------------------------------------------------------------------
-# census over real mapreduce stage callables (the cost model's inputs)
+# census over real mapreduce stage callables
 # ---------------------------------------------------------------------------
 
 def test_census_counts_dot_flops_in_reduce_stage():
     import jax
     import jax.numpy as jnp
-    from repro.core.cost_model import stage_census
 
     P, C1, C2, d = 4, 64, 96, 3
     a = jax.ShapeDtypeStruct((P, C1, d), jnp.float32)
     b = jax.ShapeDtypeStruct((P, C2, d), jnp.float32)
-    cen = stage_census(lambda x, y: jnp.einsum("pcd,ped->pce", x, y), a, b)
+    fn = lambda x, y: jnp.einsum("pcd,ped->pce", x, y)  # noqa: E731
+    cen = analyze_hlo(jax.jit(fn).lower(a, b).compile().as_text())
     # one batched dot: 2 * P * C1 * C2 * d FLOPs, reads/writes nonzero bytes
     assert cen.flops == 2.0 * P * C1 * C2 * d
     assert cen.hbm_bytes > 0
 
 
 def test_census_blocked_chunk_elementwise_flops():
-    from repro.core.cost_model import _probe_args, stage_census
+    import jax
+    import jax.numpy as jnp
     from repro.kernels.zones_pairs.blocked import _count_chunk
 
-    cen = stage_census(_count_chunk, *_probe_args(32, 32, 64))
+    tm, tn, b0 = 32, 32, 64
+    rng = np.random.default_rng(0)
+    a = rng.standard_normal((b0, tm, 3)).astype(np.float32)
+    b = rng.standard_normal((b0, tn, 3)).astype(np.float32)
+    a /= np.linalg.norm(a, axis=-1, keepdims=True)
+    b /= np.linalg.norm(b, axis=-1, keepdims=True)
+    args = (jnp.asarray(a), jnp.asarray(b), jnp.full(b0, tm, jnp.int32),
+            jnp.full(b0, tn, jnp.int32), jnp.float32(0.99))
+    cen = analyze_hlo(jax.jit(_count_chunk).lower(*args).compile().as_text())
     # the pair kernel is an unrolled broadcast-multiply-add (the bit-parity
     # contract forbids a real dot), so its work shows up as ELEMENTWISE
     # flops inside fusions — zero dot flops is load-bearing, not a gap

@@ -14,7 +14,7 @@ from repro.mapreduce import (HashPartitioner, MapReduceJob, ZonePartitioner,
                              neighbor_pairs_dense, neighbor_search_count,
                              neighbor_search_job, neighbor_statistics,
                              neighbor_statistics_job, run_job, run_jobs,
-                             token_histogram)
+                             token_histogram, token_histogram_job)
 from repro.mapreduce.codecs import Int16Codec
 
 
@@ -193,6 +193,65 @@ def test_engine_parity_batched_and_skewed():
     n_owned = np.bincount(keys, minlength=part.n_partitions(xyz))
     tiers = plan_tiers(n_owned, n_owned * 2, 64)
     assert len(tiers) >= 2
+
+
+def _tile_job(kind: str, tile: int):
+    """(job, items) for one of the three paper jobs on a small seeded input:
+    800 sky rows (5 histogram edges) or 5,000 tokens over 8 partitions."""
+    if kind == "wordcount":
+        toks = np.random.default_rng(5).integers(0, 900, 5000)
+        return token_histogram_job(900, n_partitions=8, tile=tile), toks
+    xyz = sky.make_catalog(800, 21)
+    if kind == "search":
+        return neighbor_search_job(0.07, tile=tile), xyz
+    edges = np.linspace(0.02, 0.07, 5)
+    return neighbor_statistics_job(edges / sky.ARCSEC, tile=tile), xyz
+
+
+@pytest.mark.parametrize("tile", [8, 64, 256, 1024])
+@pytest.mark.parametrize("kind", ["search", "statistics", "wordcount"])
+def test_tile_quantum_never_changes_answers(kind, tile):
+    """The capacity quantum reshapes the tiers (and the padding), never the
+    answer: the device engine at any tile equals the host engine at 256."""
+    job, items = _tile_job(kind, tile)
+    want, _ = _tile_job(kind, 256)
+    got = run_job(job, items, engine="device")
+    np.testing.assert_array_equal(
+        np.asarray(got.output),
+        np.asarray(run_job(want, items, engine="host").output))
+    assert got.stats.engine == "device"
+
+
+def _refused(setting: str):
+    xyz = sky.make_catalog(50, 0)
+    if setting == "codec":
+        run_job(neighbor_search_job(0.1, codec="auto"), xyz)
+    elif setting == "tile":
+        run_job(neighbor_search_job(0.1, tile="auto"), xyz)
+    elif setting == "split_rows":
+        run_job(neighbor_search_job(0.1), xyz, split_rows="auto")
+    else:
+        from repro.data import ArraySplits
+        from repro.mapreduce import SpillConfig, run_job_streaming
+        run_job_streaming(neighbor_search_job(0.1),
+                          ArraySplits(xyz, n_splits=2),
+                          spill=SpillConfig(budget_bytes=0, n_ranges="auto"))
+
+
+@pytest.mark.parametrize("setting,error,concrete", [
+    ("codec", KeyError, "identity"),
+    ("tile", ValueError, "tile=256"),
+    ("split_rows", ValueError, "split_rows=4096"),
+    ("n_ranges", ValueError, "n_ranges=8"),
+])
+def test_removed_auto_settings_are_refused(setting, error, concrete):
+    """``"auto"`` is no longer a value of these settings: each is refused
+    before any work runs (no span opens), naming the concrete form."""
+    from repro.obs import Tracer, use_tracer
+    with use_tracer(Tracer()) as tr:
+        with pytest.raises(error, match=concrete):
+            _refused(setting)
+    assert not tr.events
 
 
 def test_engine_parity_jnp_index_path():
@@ -464,7 +523,7 @@ def test_streaming_combiner_shrinks_wordcount_wire_bytes():
     """Map-side combine pre-aggregates each split to (token, count) rows, so
     for vocab << split size the wire carries ~vocab weighted entries instead
     of every occurrence — the paper's shrink-bytes-before-the-boundary move
-    (>=2x is the fig4 bench gate; here the duplication factor is ~8x)."""
+    (the gate is >=2x; here the duplication factor is ~8x)."""
     from repro.data import ArraySplits
     from repro.mapreduce import run_job_streaming, token_histogram_job
     rng = np.random.default_rng(0)
