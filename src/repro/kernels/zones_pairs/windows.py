@@ -16,9 +16,11 @@ at survey radii: 1.4e-5 against a squared chord of 5.3e-3 at 250 arcmin.
 
 Tile boxes are tight only when rows near each other on the sky sit near each
 other in the partition: the shuffle orders each zone by RA
-(``ZonePartitioner.sort_key_device``). Over unordered rows every box spans
-the zone, every tile pair passes, and the skip degrades to scoring all of
-them.
+(``ZonePartitioner.sort_key_device``), on one chip in
+``_scatter_tiers_jit`` and on a mesh on the chip that owns the zone, after
+the exchange (``_exchange_jit``). Where a partitioner has no sort key, rows
+keep arrival order, every box spans the zone, every tile pair passes, and
+the skip degrades to scoring all of them.
 
 The Pallas kernel (``kernel.py``) needs its kept bucket tiles as runs it can
 loop over: ``two_intervals`` covers each owned tile's kept tiles with two

@@ -116,9 +116,10 @@ class Partitioner:
     def sort_key_device(self, items):
         """Optional [n] secondary sort key: rows within a partition land in
         this order, which tightens the per-tile boxes the pair reduces skip
-        by (``ZonePartitioner`` returns RA). Order never affects results —
-        partition reductions are commutative sums — so ``None`` (arrival
-        order) is always correct."""
+        by (``ZonePartitioner`` returns RA). The cluster shuffle quantises it
+        over the fixed range ``[-pi, pi]`` (an angle; keys outside clip to
+        its ends). Order never affects results — partition reductions are
+        commutative sums — so ``None`` (arrival order) is always correct."""
         return None
 
     def bucket_entries_device(self, items, keys, n_parts: int):
@@ -626,7 +627,9 @@ def _scatter_tiers_jit(payloads, keys, dest_eff, src, skey, owned_starts,
 # order each partition by the partitioner's secondary key: the host path by
 # the key itself, the jnp path by the key quantised to the bits the
 # partition id leaves free (``_sort_keys``), so rows whose keys share a
-# quantum may lie in another order.
+# quantum may lie in another order. A mesh always takes the jnp path
+# (``_exchange_jit``), whose owners order by the key quantised over the
+# fixed range [-pi, pi] (``_ra_quanta``), one quantum for every chip and job.
 SHUFFLE_INDEX_IMPL = "auto"            # "auto" | "jnp" | "host"
 
 
@@ -700,9 +703,49 @@ def _count_entries(keys, dest_eff, *, mesh, n_parts):
         keys, dest_eff)
 
 
+def _segment_starts(layout) -> np.ndarray:
+    """The first row of every partition slot's owned and bucket rows in a
+    chip's flat tier buffer (ascending), then the buffer's length ``L``."""
+    starts = []
+    for q, C1, C2, o0, b0 in layout:
+        starts += [o0 + l * C1 for l in range(q)]
+        starts += [b0 + l * C2 for l in range(q)]
+    starts.append(max((b0 + q * C2 for q, _, C2, _, b0 in layout),
+                      default=0))
+    return np.asarray(starts, np.int32)
+
+
+def _ra_quanta(skey, bits: int):
+    """``skey`` quantised linearly over the fixed range ``[-pi, pi]`` to
+    ``bits`` bits (monotone, so order is kept up to ties)."""
+    scale = (2.0 ** bits - 1.0) / (2.0 * np.pi)
+    q = jnp.floor((skey + np.pi) * scale).astype(jnp.int32)
+    return jnp.clip(q, 0, 2 ** bits - 1)
+
+
+def _order_arrivals(pos, quanta, starts, bits: int):
+    """Re-place arrived entries so each segment (a slot's owned or bucket
+    rows, ``starts``) lies in ``quanta`` order. A segment's arrivals fill
+    its first rows whatever their order, so an entry's new row is its
+    segment's start plus its rank in the segment: ONE argsort of an int32
+    key, the segment in the high bits and ``quanta`` in the ``bits`` below
+    (a two-key sort costs the TPU compiler ~100 s more). Unused slots
+    (``pos >= L``) form the last segment, and keep distinct rows past
+    ``L``."""
+    seg = jnp.searchsorted(starts, pos, side="right").astype(jnp.int32) - 1
+    key, perm = jax.lax.sort_key_val(
+        (seg << bits) | quanta, jnp.arange(pos.shape[0], dtype=jnp.int32))
+    ss = key >> bits
+    first = jnp.searchsorted(ss, jnp.arange(starts.shape[0],
+                                            dtype=jnp.int32)).astype(jnp.int32)
+    rank = jnp.arange(pos.shape[0], dtype=jnp.int32) - first[ss]
+    return jnp.zeros_like(pos).at[perm].set(starts[ss] + rank,
+                                            unique_indices=True)
+
+
 @functools.partial(jax.jit, static_argnames=("mesh", "cap", "layout"))
-def _exchange_jit(payloads, keys, dest_eff, src, gid, send_base, dest_base,
-                  *, mesh, cap, layout):
+def _exchange_jit(payloads, keys, dest_eff, src, skey, gid, send_base,
+                  dest_base, *, mesh, cap, layout):
     """The cluster shuffle, one program: on each chip, sort its entries by
     owner, pack them into ``D`` send buffers of ``cap`` rows, exchange the
     buffers with one ``all_to_all``, and scatter what arrived into the
@@ -714,12 +757,24 @@ def _exchange_jit(payloads, keys, dest_eff, src, gid, send_base, dest_base,
     into its send slot and its row in the owner's flat tier buffer.
     ``layout``: per tier ``(q, C1, C2, owned_start, bucket_start)``, with
     ``q`` partitions a chip. -> per tier (owned wire, bucket wire), each
-    ``[Pt, C, ...]`` sharded over ``data``."""
+    ``[Pt, C, ...]`` sharded over ``data``.
+
+    ``skey`` ([n] per chip, the partitioner's sort key, or ``None``): each
+    entry carries its key quantised over ``[-pi, pi]`` (``_ra_quanta``) as
+    an int32 column beside its destination row (no collective more), and
+    the owner lays every partition's owned rows and bucket entries out in
+    that order (``_order_arrivals``): RA for zones, so the pair kernel's
+    tile windows are short. The order has to be made on the owner, since a
+    partition arrives in ``D`` runs, one a sender. With ``None`` each
+    partition keeps its arrival order: chip order, and each chip's entries
+    in stream order."""
     D = _data_axis_size(mesh)
     n_parts = gid.shape[0] // 2 - 1
-    L = max((b0 + q * C2 for q, _, C2, _, b0 in layout), default=0)
+    starts = _segment_starts(layout)
+    L = int(starts[-1])
+    bits = 31 - (len(starts) - 1).bit_length()      # segment ids 0..nseg
 
-    def body(payloads, keys, dest_eff, src, gid, send_base, dest_base):
+    def body(payloads, keys, dest_eff, src, skey, gid, send_base, dest_base):
         ids = jnp.concatenate([keys, dest_eff + (n_parts + 1)])
         rows = jnp.concatenate([jnp.arange(keys.shape[0], dtype=jnp.int32),
                                 src])
@@ -735,11 +790,18 @@ def _exchange_jit(payloads, keys, dest_eff, src, gid, send_base, dest_base,
         # unused slots point past the tier buffer, each at its own row
         unused = (L + jax.lax.axis_index("data") * (D * cap)
                   + jnp.arange(D * cap, dtype=jnp.int32))
-        send.append(unused.at[slot].set(dest_base[0, gs] + i, mode="drop",
+        dest = dest_base[0, gs] + i
+        if skey is not None:      # the key rides beside the destination row
+            unused = jnp.stack([unused, jnp.zeros_like(unused)], axis=1)
+            dest = jnp.stack([dest, _ra_quanta(skey, bits)[take]], axis=1)
+        send.append(unused.at[slot].set(dest, mode="drop",
                                         unique_indices=True))
         recv = [jax.lax.all_to_all(x, "data", 0, 0, tiled=True)
                 for x in send]
         pos = recv.pop()
+        if skey is not None:
+            pos = _order_arrivals(pos[:, 0], pos[:, 1], jnp.asarray(starts),
+                                  bits)
         flat = [jnp.zeros((L,) + r.shape[1:], r.dtype)
                 .at[pos].set(r, mode="drop", unique_indices=True)
                 for r in recv]
@@ -753,9 +815,9 @@ def _exchange_jit(payloads, keys, dest_eff, src, gid, send_base, dest_base,
 
     return _shard_map_compat(
         body, mesh=mesh,
-        in_specs=(_ROW, _ROW, _ROW, _ROW, PartitionSpec(), _ROW, _ROW),
+        in_specs=(_ROW, _ROW, _ROW, _ROW, _ROW, PartitionSpec(), _ROW, _ROW),
         out_specs=_ROW, axis_names=frozenset({"data"}))(
-        payloads, keys, dest_eff, src, gid, send_base, dest_base)
+        payloads, keys, dest_eff, src, skey, gid, send_base, dest_base)
 
 
 def _exchange_plan(counts, plan, specs, D: int):
@@ -1214,8 +1276,8 @@ def _shuffle_mapped(partitioner: Partitioner, codec: ShuffleCodec, tile: int,
                                    "exchange_rows": crossing}):
                 scattered = jax.block_until_ready(_exchange_jit(
                     stream.payloads, stream.keys, stream.dest_eff, stream.src,
-                    gid, send_base, dest_base, mesh=mesh, cap=cap,
-                    layout=layout))
+                    stream.skey, gid, send_base, dest_base, mesh=mesh,
+                    cap=cap, layout=layout))
             stats.exchange_rows += crossing
             stats.exchange_bytes += crossing * row_bytes
         else:
@@ -1273,7 +1335,9 @@ def _blocked_stream(blocks, mesh, P: int) -> MappedSplit:
     """Per-chip map outputs as one stream of ``[D * length, ...]`` arrays
     sharded over ``data``, each chip's rows and entries padded to a common
     length (padding rows and entries carry id ``P``: nobody's); ``src``
-    stays chip-local."""
+    stays chip-local. The sort key comes along, sharded like ``keys``
+    (padding rows 0), so ``_exchange_jit`` can order each partition by it;
+    ``None`` where the partitioner has none."""
     m = next(b for b in blocks if b is not None)
     n = max(max((b.n_rows for b in blocks if b is not None), default=0), 1)
     e = max(max((b.dest_eff.shape[0] for b in blocks if b is not None),
@@ -1289,7 +1353,8 @@ def _blocked_stream(blocks, mesh, P: int) -> MappedSplit:
         keys=rows(lambda b: b.keys, n, P),
         dest_eff=rows(lambda b: b.dest_eff, e, P),
         src=rows(lambda b: b.src, e, 0),
-        skey=None, n_rows=sum(b.n_rows for b in blocks if b is not None),
+        skey=None if m.skey is None else rows(lambda b: b.skey, n, 0),
+        n_rows=sum(b.n_rows for b in blocks if b is not None),
         d=m.d)
 
 

@@ -465,8 +465,16 @@ def check_mapreduce_cluster():
         partition in any order), padding and phantom partitions zero;
     (c) the ``shuffle.exchange`` span's byte counter equals the owned rows
         and bucket entries whose owner is not their block's chip, times the
-        wire bytes of a row.
+        wire bytes of a row;
+    (d) the owner lays each partition's owned rows and bucket entries out
+        in RA order, to within one quantum of the exchange's fixed-range
+        key (the mesh twin of ``test_shuffle_orders_each_zone_by_ra``);
+    (e) while a tracer records, the mesh run's pair kernel scores at most
+        1.05 times the tile pairs of the one-device run, and a copy of the
+        partitioner with no sort key (arrival order) scores every one.
     """
+    import dataclasses
+
     import jax.numpy as jnp
     from repro.data import sky
     from repro.mapreduce import (ZonePartitioner, neighbor_search_job,
@@ -507,7 +515,7 @@ def check_mapreduce_cluster():
                 xyz, radius)
             np.testing.assert_array_equal(r4[1].output, rh[1].output)
 
-    # (b), (c)
+    # (b), (c), (d)
     codec = "identity"
     tracer = Tracer()
     with use_tracer(tracer):
@@ -521,6 +529,11 @@ def check_mapreduce_cluster():
     def canon(rows):
         return rows[np.lexsort(rows.T[::-1])]
 
+    # one quantum of the exchange's RA key (``job._ra_quanta``), or the
+    # float32 rounding of RA + pi where that is coarser
+    nseg = 2 * sum(t.Pt // D for t in cat.sd.tiers)
+    quantum = max(2 * np.pi / (2 ** (31 - nseg.bit_length()) - 1),
+                  float(np.spacing(np.float32(2 * np.pi))))
     owner = np.full(cat.P + 1, D)
     for t in cat.sd.tiers:
         q = t.Pt // D
@@ -547,6 +560,8 @@ def check_mapreduce_cluster():
                     np.testing.assert_array_equal(canon(local[j, :n]),
                                                   canon(ref[:n]))
                     assert not local[j, n:].any(), (name, p)
+                    ra = np.arctan2(local[j, :n, 1], local[j, :n, 0])
+                    assert (np.diff(ra) >= -quantum).all(), (name, p)
             assert len(chips) == D, (name, chips)
 
     # independent count of the entries that leave their home chip
@@ -567,8 +582,28 @@ def check_mapreduce_cluster():
     assert cat.load_stats.exchange_bytes == crossing * row_bytes
     n_entries = len(xyz) + int(valid.sum())
     assert 0.6 < crossing / n_entries < 0.9, crossing / n_entries
+
+    # (e)
+    @dataclasses.dataclass(frozen=True)
+    class ArrivalOrder(ZonePartitioner):
+        def sort_key_device(self, items):
+            return None
+
+    def tiles(partitioner, mesh):
+        job = neighbor_statistics_job(edges / sky.ARCSEC, tile=tile,
+                                      partitioner=partitioner)
+        with use_tracer(Tracer()):
+            st = run_jobs([job], xyz, mesh=mesh, engine="device")[0].stats
+        return st.pair_tiles_scored, st.pair_tiles_real
+
+    scored4, real = tiles(part, mesh)
+    scored1, real1 = tiles(part, None)
+    assert real == real1 > 0, (real, real1)
+    assert scored4 <= 1.05 * scored1, (scored4, scored1)
+    assert tiles(ArrivalOrder(radius), mesh) == (real, real)
     print(f"mapreduce cluster shuffle on 4 chips OK ({crossing} of "
-          f"{n_entries} entries crossed)")
+          f"{n_entries} entries crossed; tile pairs scored {scored4} on the "
+          f"mesh, {scored1} on one device, of {real})")
 
 
 if __name__ == "__main__":

@@ -334,6 +334,44 @@ def test_shuffle_orders_each_zone_by_ra():
     np.testing.assert_array_equal(got["host"][1], got["jnp"][1])
 
 
+@pytest.mark.parametrize("seed", [0, 1])
+def test_exchange_orders_each_segment_on_the_owner(seed):
+    """The owner's re-placement after the cluster exchange
+    (``job._order_arrivals``), against a numpy reference: each slot's owned
+    or bucket rows, arriving in any order from several senders, take the
+    slot's first rows in order of their RA quanta (ties in arrival order);
+    unused receive slots keep distinct rows past the tier buffer."""
+    import jax.numpy as jnp
+
+    from repro.mapreduce.job import (_order_arrivals, _ra_quanta,
+                                     _segment_starts)
+    rng = np.random.default_rng(seed)
+    layout = ((2, 8, 16, 0, 16), (1, 32, 64, 48, 80))  # (q, C1, C2, o0, b0)
+    starts = _segment_starts(layout)
+    L = int(starts[-1])
+    assert list(starts) == [0, 8, 16, 32, 48, 80, 144]
+    n_seg = [5, 8, 0, 11, 20, 64]                 # arrivals per segment
+    pos = np.concatenate([s + rng.permutation(n) for s, n in
+                          zip(starts[:-1], n_seg)] + [L + np.arange(30)])
+    pos = pos[rng.permutation(len(pos))].astype(np.int32)
+    bits = 31 - (len(starts) - 1).bit_length()
+    ra = rng.uniform(-np.pi, np.pi, len(pos)).astype(np.float32)
+    ra[:12] = ra[12]                              # ties keep arrival order
+    quanta = np.asarray(_ra_quanta(jnp.asarray(ra), bits))
+    got = np.asarray(_order_arrivals(jnp.asarray(pos), jnp.asarray(quanta),
+                                     jnp.asarray(starts), bits))
+    want = pos.copy()
+    seg = np.searchsorted(starts, pos, side="right") - 1
+    for s in range(len(starts) - 1):
+        idx = np.flatnonzero(seg == s)
+        want[idx[np.argsort(quanta[idx], kind="stable")]] = (
+            starts[s] + np.arange(len(idx)))
+    used = pos < L
+    np.testing.assert_array_equal(got[used], want[used])
+    assert (got[~used] >= L).all() and len(set(got[~used])) == (~used).sum()
+    assert len(np.unique(quanta)) > len(quanta) // 2     # not vacuous
+
+
 def test_pair_tile_counter_reads_the_window_share():
     """While a tracer records, the reduce reports the tile pairs its kernel
     scored against those of real rows, on ``StageStats`` and on the

@@ -63,6 +63,7 @@ def test_mapreduce_cluster_shuffle_multidevice():
     """Four-chip cluster shuffle (4 host devices): per-chip map, one
     all-to-all to the partition owners, tiers born sharded. Bit-identical
     to the one-device path and the host engine; each chip's tier slices
-    hold the one-device rows of its partitions; the exchange's byte
-    counter equals the entries that left their home chip."""
+    hold the one-device rows of its partitions, each in RA order; the
+    exchange's byte counter equals the entries that left their home chip;
+    the pair kernel's windows keep as few tile pairs as on one device."""
     assert "OK" in _run("mapreduce-cluster", timeout=300)

@@ -143,7 +143,8 @@ def test_cluster_exchange_compiles_for_v5e_2x2(topo, no_persistent_cache):
     """The cluster shuffle's one program (sort, pack, ``all_to_all``,
     scatter into the tiers) for four chips at the DES Y3 redMaGiC cell's
     block size: 865,058 rows over 8 zones of 250 arcmin, 216,265 rows a
-    chip, ``identity`` wire rows, every row copied into both neighbours."""
+    chip, ``identity`` wire rows, every row copied into both neighbours,
+    each partition ordered by its RA key on the owner."""
     from repro.mapreduce.job import (_exchange_jit, _exchange_plan,
                                      _round_up, plan_tiers)
     D, rows, d = 4, 865_058, 3
@@ -174,10 +175,13 @@ def test_cluster_exchange_compiles_for_v5e_2x2(topo, no_persistent_cache):
     compiled = _exchange_jit.lower(
         (shape(D * block, d, dtype=jnp.float32),), shape(D * block),
         shape(D * 3 * block), shape(D * 3 * block),
+        shape(D * block, dtype=jnp.float32),
         shape(len(gid), sharding=NamedSharding(mesh, P())),
         shape(D, len(gid)), shape(D, len(gid)),
         mesh=mesh, cap=cap, layout=layout).compile()
     text = compiled.as_text()
-    assert "all-to-all" in text
+    # one all-to-all of the payload and one of the destination rows, the
+    # RA key riding beside them: the order costs no collective more
+    assert len(re.findall(r"\ball-to-all\(", text)) == 2
     assert len(compiled.out_info) == len(plan)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
